@@ -10,6 +10,7 @@ from __future__ import annotations
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.sim import SimConfig, Simulator
 
 FULL = os.environ.get("REPRO_BENCH_SCALE", "small") == "full"
@@ -19,6 +20,7 @@ EVAL_EVERY = 20 if FULL else 15
 
 
 def run_variant(name: str, **overrides):
+    enable_compile_cache()
     cfg_kw = dict(n_learners=N_LEARNERS, rounds=ROUNDS, eval_every=EVAL_EVERY,
                   seed=overrides.pop("seed", 0))
     cfg_kw.update(overrides)

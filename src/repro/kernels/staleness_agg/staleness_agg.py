@@ -33,17 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.staleness import EPS, SCALING_RULES
+from repro.kernels import resolve_interpret
 
 D_BLK = 2048  # lane-aligned (16 x 128); (n<=64) x 2048 fp32 = 512 KB per operand
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode unless running on a real TPU backend."""
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret):
-    return default_interpret() if interpret is None else interpret
 
 
 def _deviation_increments(u, fresh):
@@ -208,7 +200,7 @@ def _make_sweep_fused_kernel(rule: str):
 
         @pl.when((p == 1) & (i == 0))
         def _weights():
-            w = _compute_weights(rule, fresh, tau_ref[0], beta_ref[0, 0],
+            w = _compute_weights(rule, fresh, tau_ref[0], beta_ref[0, 0, 0],
                                  num_ref[0], den_ref[0], valid_ref[0])
             w_ref[...] = w.reshape(w_ref.shape)
 
@@ -250,7 +242,7 @@ def _make_sweep_fused_apply_kernel(rule: str):
 
         @pl.when((p == 1) & (i == 0))
         def _weights():
-            w = _compute_weights(rule, fresh, tau_ref[0], scal_ref[0, 0],
+            w = _compute_weights(rule, fresh, tau_ref[0], scal_ref[0, 0, 0],
                                  num_ref[0], den_ref[0], valid_ref[0])
             w_ref[...] = w.reshape(w_ref.shape)
 
@@ -258,7 +250,7 @@ def _make_sweep_fused_apply_kernel(rule: str):
         def _apply():
             agg = jnp.dot(w_ref[0], u_ref[0],
                           preferred_element_type=jnp.float32)
-            out_ref[...] = params_ref[...] + scal_ref[0, 1] * agg
+            out_ref[0] = params_ref[0] + scal_ref[0, 0, 1] * agg
 
     return kernel
 
@@ -275,8 +267,12 @@ def sweep_fused_staleness_apply(params, updates, fresh, tau, valid, scal, *,
     applies the aggregate to the cell's parameter row in place.  Returns
     (new_params (S, D), weights (S, n)); all-invalid cells get zero weights
     and therefore keep their parameter bits.
+
+    Per-cell operands enter the kernel with a unit middle axis (params
+    ``(S, 1, D)``, scal ``(S, 1, 2)``) so that the last two dims of every
+    block equal the array's, which the TPU lowering requires for S > 1.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     s, n, d = updates.shape
     assert d % D_BLK == 0 and params.shape == (s, d)
     grid = (s, 2, d // D_BLK)
@@ -284,34 +280,34 @@ def sweep_fused_staleness_apply(params, updates, fresh, tau, valid, scal, *,
         _make_sweep_fused_apply_kernel(rule),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, D_BLK), lambda s_, p, i: (s_, i)),
+            pl.BlockSpec((1, 1, D_BLK), lambda s_, p, i: (s_, 0, i)),
             pl.BlockSpec((1, n, D_BLK), lambda s_, p, i: (s_, 0, i)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
-            pl.BlockSpec((1, 2), lambda s_, p, i: (s_, 0)),
+            pl.BlockSpec((1, 1, 2), lambda s_, p, i: (s_, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, D_BLK), lambda s_, p, i: (s_, i)),
+            pl.BlockSpec((1, 1, D_BLK), lambda s_, p, i: (s_, 0, i)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, 1, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, 1, n), lambda s_, p, i: (s_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((s, d), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((s, n, 1), jnp.float32),
             jax.ShapeDtypeStruct((s, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((s, 1, n), jnp.float32),
         ],
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(params.astype(jnp.float32),
+    )(params.astype(jnp.float32)[:, None],
       updates.astype(jnp.float32),
       fresh.astype(jnp.float32)[..., None],
       tau.astype(jnp.float32)[..., None],
       valid.astype(jnp.float32)[..., None],
-      scal.astype(jnp.float32))
-    return new_params, w[:, 0]
+      scal.astype(jnp.float32)[:, None])
+    return new_params[:, 0], w[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "interpret"))
@@ -323,9 +319,10 @@ def sweep_fused_staleness_aggregate(updates, fresh, tau, beta, valid, *,
     One kernel launch aggregates S simulations' rounds: per-cell deviation
     partials, in-kernel per-cell Eq. 2 weights, per-cell weighted aggregate.
     Returns (aggregate (S, D), weights (S, n)); all-invalid cells produce
-    zero weights and a zero aggregate row.
+    zero weights and a zero aggregate row.  beta enters as ``(S, 1, 1)``
+    for the TPU block rule (see ``sweep_fused_staleness_apply``).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     s, n, d = updates.shape
     assert d % D_BLK == 0
     grid = (s, 2, d // D_BLK)
@@ -337,7 +334,7 @@ def sweep_fused_staleness_aggregate(updates, fresh, tau, beta, valid, *,
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
-            pl.BlockSpec((1, 1), lambda s_, p, i: (s_, 0)),
+            pl.BlockSpec((1, 1, 1), lambda s_, p, i: (s_, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, n, 1), lambda s_, p, i: (s_, 0, 0)),
@@ -356,7 +353,7 @@ def sweep_fused_staleness_aggregate(updates, fresh, tau, beta, valid, *,
       fresh.astype(jnp.float32)[..., None],
       tau.astype(jnp.float32)[..., None],
       valid.astype(jnp.float32)[..., None],
-      beta.astype(jnp.float32)[:, None])
+      beta.astype(jnp.float32)[:, None, None])
     return out[:, 0], w[:, 0]
 
 
@@ -369,7 +366,7 @@ def fused_staleness_aggregate(updates, fresh, tau, beta, *, rule="relay",
     aggregate. ``valid`` (n,) bool masks bucket-padding rows (default: all).
     Returns (aggregate (D,), weights (n,)).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     n, D = updates.shape
     assert D % D_BLK == 0
     if valid is None:
@@ -415,7 +412,7 @@ def fused_staleness_apply(params, updates, fresh, tau, beta, server_lr, *,
     (``input_output_aliases``), so the update is in-place within the program.
     params: (D,) fp32 (D % D_BLK == 0). Returns (new_params (D,), weights (n,)).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     n, D = updates.shape
     assert D % D_BLK == 0 and params.shape == (D,)
     if valid is None:
@@ -462,7 +459,7 @@ def deviation_partials(updates, fresh, *, interpret=None):
 
     Returns (num (n,), den ()) such that Lam = num / (den + eps).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     n, D = updates.shape
     assert D % D_BLK == 0
     grid = (D // D_BLK,)
@@ -489,7 +486,7 @@ def deviation_partials(updates, fresh, *, interpret=None):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def weighted_aggregate(weights, updates, *, interpret=None):
     """weights: (n,) fp32; updates: (n, D) -> (D,)."""
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     n, D = updates.shape
     assert D % D_BLK == 0
     out = pl.pallas_call(

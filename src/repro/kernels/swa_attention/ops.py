@@ -10,12 +10,13 @@ from repro.kernels.swa_attention.swa_attention import BLK, swa_attention_bhsd
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def swa_attention(q, k, v, *, window: int, interpret: bool = True):
+def swa_attention(q, k, v, *, window: int, interpret: bool | None = None):
     """q: (B, S, H, Dh); k, v: (B, S, Hkv, Dh) -> (B, S, H, Dh).
 
     Pads S to the 128 block and window to a block multiple (a slightly larger
     window is attention-superset-safe only at block granularity, so we keep
     the *exact* window by requiring window % BLK == 0 — configs use 8192).
+    Forward only: there is no VJP, so ``jax.grad`` through it fails.
     """
     assert window % BLK == 0, "window must be a multiple of the 128 tile"
     B, S, H, Dh = q.shape

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BLK = 128
 NEG_INF = -1e30
 
@@ -63,7 +65,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("window", "n_kv_heads", "interpret"))
 def swa_attention_bhsd(q, k, v, *, window: int, n_kv_heads: int,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """q: (BH, S, Dh); k, v: (B*Hkv, S, Dh); S % BLK == 0; window % BLK == 0.
 
     Query head bh maps to kv head bh // (H // Hkv) via the BlockSpec index map.
@@ -97,5 +99,5 @@ def swa_attention_bhsd(q, k, v, *, window: int, n_kv_heads: int,
             pltpu.VMEM((BLK,), jnp.float32),
             pltpu.VMEM((BLK, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

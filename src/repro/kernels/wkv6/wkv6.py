@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 CHUNK = 128
 
 
@@ -59,7 +61,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, s_out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wkv6_bhsn(r, k, v, w, u, s0, *, interpret: bool = True):
+def wkv6_bhsn(r, k, v, w, u, s0, *, interpret: bool | None = None):
     """r,k,v,w: (BH, S, N); u: (BH, 1, N); s0: (BH, N, N); S % CHUNK == 0.
 
     Returns (y (BH, S, N), s_final (BH, N, N)).
@@ -85,6 +87,6 @@ def wkv6_bhsn(r, k, v, w, u, s0, *, interpret: bool = True):
             jax.ShapeDtypeStruct((BH, N, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u, s0)
     return y, s_fin

@@ -77,7 +77,18 @@ def _base_cfg(knobs: dict, meta, **over) -> tf.ModelConfig:
         **over)
 
 
+def _check_kernels_trainable(knobs: dict, why: str) -> None:
+    """A learner trains through its kernels, so ``use_kernels=1`` on a TPU
+    backend raises instead of running the Pallas interpreter there."""
+    if int(knobs["use_kernels"]) and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"use_kernels=1 cannot train on TPU: {why} (ROADMAP 2.3); "
+            f"set use_kernels=0")
+
+
 def _build_transformer(knobs: dict, meta) -> ModelFns:
+    _check_kernels_trainable(
+        knobs, "the swa_attention Pallas kernel is forward-only (no VJP)")
     window = int(knobs["window"])
     return _fns_for(_base_cfg(
         knobs, meta, arch_id="fl-transformer",
@@ -93,6 +104,8 @@ def _build_moe(knobs: dict, meta) -> ModelFns:
 
 
 def _build_rwkv6(knobs: dict, meta) -> ModelFns:
+    _check_kernels_trainable(
+        knobs, "the wkv6 Pallas kernel has no TPU lowering and no VJP")
     return _fns_for(_base_cfg(
         knobs, meta, arch_id="fl-rwkv6", family="hybrid",
         block_pattern=("rwkv6",),

@@ -157,12 +157,17 @@ def gqa_forward(params, x, positions, *, n_heads, n_kv_heads, d_head,
 
     ``use_kernel`` routes sliding-window attention through the Pallas
     flash-SWA kernel (requires a window that is a multiple of its 128 tile and
-    contiguous positions — i.e. the standard prefill layout).
+    contiguous positions — i.e. the standard prefill layout); any other
+    window raises rather than quietly taking the jnp path.
     """
     B, S, _ = x.shape
     G = n_heads // n_kv_heads
     q, k, v = gqa_project_qkv(params, x, n_heads, n_kv_heads, d_head, positions, rope_theta)
-    if use_kernel and window is not None and window % 128 == 0:
+    if use_kernel:
+        if window is None or window % 128:
+            raise ValueError(
+                f"use_kernels needs a sliding window that is a multiple of "
+                f"the kernel's 128 tile, got window={window}")
         from repro.kernels.swa_attention import ops as swa_ops
         out = swa_ops.swa_attention(q, k, v, window=window)
         out = out.reshape(B, S, n_heads * d_head)

@@ -103,7 +103,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation as agg
@@ -613,14 +612,14 @@ def _sharded_chunk_program(spec, lr, prox_mu, steps, batch, yogi, use_kernel,
             return (p[None], c[None], jax.tree.map(lambda a: a[None], o),
                     losses, l2s, gst, lns)
 
-        return shard_map(
+        return jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(P("s"), P(("s", "p")), opt_spec, P(), P(),
                       P(None, ("s", "p")), P(None, ("s", "p"))),
             out_specs=(P("s"), P(("s", "p")), opt_spec,
                        P(None, ("s", "p")), P(None, ("s", "p")),
                        P(None, ("s", "p")), P(None, ("s", "p"))),
-            check_rep=False,
+            check_vma=False,
         )(params3, cache3, opt_state, x_tr, y_tr, ints3, floats3)
 
     return jax.jit(prog, donate_argnums=(0, 1, 2), static_argnums=(7,))

@@ -113,6 +113,8 @@ def main(argv=None) -> None:
             print(describe_models())
         return
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     telemetry = None
     if args.telemetry_dir:
         from repro.telemetry import TelemetrySession

@@ -35,11 +35,8 @@ class Tracer:
         self._t0 = time.perf_counter_ns()
         self._annotation = None
         if jax_profiler:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotation = TraceAnnotation
-            except Exception:  # pragma: no cover — old jax without profiler
-                self._annotation = None
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     def span(self, name: str, **args):
         if not self.enabled:

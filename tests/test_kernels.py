@@ -191,3 +191,12 @@ def test_wkv6_state_continuation():
                                np.asarray(y_full), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full),
                                rtol=1e-4, atol=1e-5)
+
+
+def test_wkv6_compiled_call_raises():
+    """wkv6 has no TPU lowering: asking for the compiled kernel raises a
+    clear error up front instead of failing inside the compiler."""
+    B, S, H, N = 1, 128, 1, 8
+    x = jnp.zeros((B, S, H, N), jnp.float32)
+    with pytest.raises(NotImplementedError, match="no TPU lowering"):
+        wkv_ops.wkv6(x, x, x, x + 0.9, jnp.zeros((H, N)), interpret=False)

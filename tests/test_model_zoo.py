@@ -284,3 +284,18 @@ def test_model_table_lists_the_zoo():
     fns = build_model("transformer", TINY_LM, meta)
     assert fns is build_model("transformer", TINY_LM, meta), \
         "build_model must return cached-identical function objects"
+
+
+@pytest.mark.parametrize("name", ["transformer", "rwkv6"])
+def test_use_kernels_on_tpu_backend_raises(name, monkeypatch):
+    """A learner trains through its kernels, and neither kernel has a VJP
+    (wkv6 has no TPU lowering either): on a TPU backend, use_kernels=1
+    fails when the learner is built instead of running the Pallas
+    interpreter there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = MODEL_TABLE[name]
+    knobs = dict({k.name: k.default for k in spec.knobs}, use_kernels=1)
+    meta = DataMeta(kind="tokens", vocab=64, seq_len=8)
+    with pytest.raises(NotImplementedError, match="use_kernels=1"):
+        spec.build(knobs, meta)
+    spec.build(dict(knobs, use_kernels=0), meta)    # the jnp path builds

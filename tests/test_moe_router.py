@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.models.moe import (load_balance_loss, moe_forward, moe_init,
@@ -103,6 +104,21 @@ def test_swa_kernel_path_matches_blocked_in_model():
     x2, _, _ = forward(dataclasses.replace(cfg, use_kernels=True), params, toks)
     np.testing.assert_allclose(np.asarray(x1), np.asarray(x2),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_swa_kernel_path_rejects_untileable_window(window):
+    """use_kernels with no window, or one off the kernel's 128 tile, raises
+    instead of quietly taking the blocked-jnp path."""
+    from repro.models import ModelConfig, init_params
+    from repro.models.transformer import forward
+    cfg = ModelConfig(n_layers=1, d_model=32, n_heads=2, n_kv_heads=2,
+                      d_ff=64, vocab_size=97, window=window,
+                      use_kernels=True, param_dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = {"tokens": jnp.zeros((1, 128), jnp.int32)}
+    with pytest.raises(ValueError, match="multiple of"):
+        forward(cfg, params, toks)
 
 
 def test_fedprox_local_train():
