@@ -5,11 +5,12 @@
 
 Every phase drives the system through its user entry points
 (``Simulator``/``SimConfig``, ``SweepRunner``) in this one process, and
-prints one line: wall seconds, compile seconds (XLA backend compilation,
-from ``jax.monitoring``; a persistent-cache hit counts none) and the
-outcome of its comparison.  A comparison outside its tolerance raises,
-and so does any other failure: nothing is caught, and the exit code is
-non-zero.
+prints one line: wall seconds, compile seconds (the union of the
+program's ``compile`` spans: tracing, lowering and backend compiles,
+persistent-cache loads included), the programs lowered and the cache
+hits, and the outcome of its comparison.  A comparison outside its
+tolerance raises, and so does any other failure: nothing is caught, and
+the exit code is non-zero.
 
 One chip:
 
@@ -68,21 +69,6 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 PARAM_RTOL = 1e-2
 ACC_ATOL = 0.02
 LOSS_RTOL = 1e-2
-
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class CompileClock:
-    """Seconds of XLA backend compilation (a ``jax.monitoring`` duration
-    listener).  Tracing is left out: its events nest, so summing them
-    would count a nested jit twice."""
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def __call__(self, event, duration, **_):
-        if event == BACKEND_COMPILE_EVENT:
-            self.seconds += duration
 
 
 @dataclasses.dataclass
@@ -262,11 +248,20 @@ def phase_sweep_mesh(n_learners=1000, rounds=20, eval_every=10) -> str:
         for a, b in zip(ref, got))
 
 
-def run_phase(name: str, clock: CompileClock, fn, *args) -> None:
-    c0, t0 = clock.seconds, time.perf_counter()
+def run_phase(name: str, tele, fn, *args) -> None:
+    """Runs one phase; ``tele`` is an open enabled session, which records
+    every compile of the process as ``compile`` spans and counters."""
+    from repro.telemetry import compile as compile_spans
+    reg = tele.registry
+    n0, t0 = len(tele.tracer.events), time.perf_counter()
+    lowered0 = reg.value("compile_programs_lowered")
+    hits0 = reg.value("compile_cache_hits")
     outcome = fn(*args)
     wall = time.perf_counter() - t0
-    print(f"{name}: wall_s={wall!r} compile_s={clock.seconds - c0!r} "
+    compile_s = compile_spans.seconds(tele.tracer.events[n0:])
+    print(f"{name}: wall_s={wall!r} compile_s={compile_s!r} "
+          f"lowered={reg.value('compile_programs_lowered') - lowered0} "
+          f"cache_hits={reg.value('compile_cache_hits') - hits0} "
           f"{outcome}", flush=True)
 
 
@@ -296,17 +291,18 @@ def main(argv=None) -> int:
         raise AssertionError("Pallas kernels would run in interpret mode")
     print(f"# {len(devices)} x {devices[0].device_kind}; compile cache "
           f"{cache_dir}", flush=True)
-    clock = CompileClock()
-    jax.monitoring.register_event_duration_secs_listener(clock)
+    from repro.telemetry import TelemetrySession, Tracer
+    tele = TelemetrySession(tracer=Tracer(enabled=True))
 
     if args.four_chips:
-        run_phase("participant_mesh", clock, phase_participant_mesh)
-        run_phase("sweep_mesh", clock, phase_sweep_mesh)
+        run_phase("participant_mesh", tele, phase_participant_mesh)
+        run_phase("sweep_mesh", tele, phase_sweep_mesh)
     else:
         for selector in ("oort", "priority"):
-            run_phase(f"serial[{selector}]", clock, phase_serial, selector)
-        run_phase("sweep", clock, phase_sweep)
-        run_phase("lm", clock, phase_lm)
+            run_phase(f"serial[{selector}]", tele, phase_serial, selector)
+        run_phase("sweep", tele, phase_sweep)
+        run_phase("lm", tele, phase_lm)
+    tele.close()
 
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,

@@ -65,6 +65,7 @@ from __future__ import annotations
 import copy
 import dataclasses
 import functools
+import time
 from typing import Optional
 
 import jax
@@ -87,6 +88,7 @@ from repro.sim import learner as ln
 from repro.sim import partition as part
 from repro.sim import traces as tr
 from repro.sim.metrics import Accounting, RoundRecord
+from repro.telemetry.trace import capture_span
 
 HOUR = 3600.0
 
@@ -404,6 +406,23 @@ class RoundSchedule:
 class Simulator:
     def __init__(self, cfg: SimConfig, substrate: Optional[Substrate] = None,
                  fault_plan=None):
+        # no telemetry session exists yet: the span's bounds wait for the
+        # run's session (``_hand_over_build``), and a running profiler
+        # capture is annotated directly
+        t0 = time.perf_counter_ns()
+        with capture_span("build"):
+            self._build(cfg, substrate, fault_plan)
+        self._build_ns = (t0, time.perf_counter_ns())
+
+    def _hand_over_build(self, telemetry) -> None:
+        """Record this Simulator's construction as a ``build`` span of the
+        session that runs it (once)."""
+        if self._build_ns is not None:
+            telemetry.complete("build", *self._build_ns)
+            self._build_ns = None
+
+    def _build(self, cfg: SimConfig, substrate: Optional[Substrate],
+               fault_plan) -> None:
         self.cfg = cfg
         self.fault_plan = fault_plan  # repro.faults.FaultPlan or None
         if cfg.attack != "none" and cfg.attack_frac > 0:
@@ -1021,6 +1040,7 @@ class Simulator:
         if telemetry is None:
             from repro.telemetry import TelemetrySession
             telemetry = TelemetrySession()
+        self._hand_over_build(telemetry)
         for r in range(start_round, cfg.rounds):
             with telemetry.span("schedule", round=r):
                 plan = self._begin_round(r)
@@ -1053,4 +1073,5 @@ class Simulator:
                 telemetry.event("crash", round=int(r), mode=fp.crash_mode)
                 telemetry.flush()
                 fp.trigger_crash(r)
-        return self._finalize()
+        with telemetry.span("finalize", cells=1):
+            return self._finalize()

@@ -196,6 +196,13 @@ class PipelineStats:
     feedback_fetches = property(
         lambda s: s._counter("feedback_fetches").value,
         lambda s, v: setattr(s._counter("feedback_fetches"), "value", v))
+    # packed participant rows that train vs the padding of their bucket
+    trained_rows = property(
+        lambda s: s._counter("trained_rows").value,
+        lambda s, v: setattr(s._counter("trained_rows"), "value", v))
+    pad_rows = property(
+        lambda s: s._counter("pad_rows").value,
+        lambda s, v: setattr(s._counter("pad_rows"), "value", v))
 
     def as_dict(self) -> dict:
         per_round = max(self.rounds, 1)
@@ -214,6 +221,8 @@ class PipelineStats:
             "rounds_per_dispatch": self.rounds_per_dispatch,
             "cross_shard_landings": self.cross_shard_landings,
             "feedback_fetches": self.feedback_fetches,
+            "trained_rows": self.trained_rows,
+            "pad_rows": self.pad_rows,
             "guard": dict(self.guard),
         }
 
@@ -281,6 +290,11 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     rejects is still visible), and the guard tail mirrors ``gstats``.
     Computed after the psum → no extra collective; lane off returns a
     zero-width block, so the program's outputs and numerics are untouched.
+
+    Named scopes ``train`` / ``cache`` (scatter and operand gather) /
+    ``aggregate`` (screens, SAA weights and aggregate; the fused kernel
+    also applies) / ``apply`` (server step) name the device ops in a
+    profile; they add metadata only, no op.
     """
     r_b, tb, g_b, nf_b, ns_b, all_valid = shapes
     n_b = nf_b + ns_b
@@ -311,174 +325,179 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     beta_g, lr_g = floats[:g_b], floats[g_b:2 * g_b]
 
     # --- train: gather batches + per-row params, one vmapped call ---
-    # trailing sample dims ride along untouched: (dim,) features for the
-    # classifier benchmarks, (S,) token sequences (x AND y) for the LM ones
-    bx = x_tr[row_sub[:, None], batch_idx]            # (R, steps*batch, ...)
-    bx = bx.reshape((r_b, steps, batch) + bx.shape[2:])
-    by = y_tr[row_sub[:, None], batch_idx]
-    by = by.reshape((r_b, steps, batch) + by.shape[2:])
-    if single:
-        deltas, losses, l2s = jax.vmap(
-            train_unit, in_axes=(None, 0, 0))(params[0], bx, by)
-    else:
-        deltas, losses, l2s = jax.vmap(train_unit)(params[row_cell], bx, by)
+    with jax.named_scope("train"):
+        # trailing sample dims ride along untouched: (dim,) features for
+        # the classifier benchmarks, (S,) token sequences (x AND y) for the
+        # LM ones
+        bx = x_tr[row_sub[:, None], batch_idx]        # (R, steps*batch, ...)
+        bx = bx.reshape((r_b, steps, batch) + bx.shape[2:])
+        by = y_tr[row_sub[:, None], batch_idx]
+        by = by.reshape((r_b, steps, batch) + by.shape[2:])
+        if single:
+            deltas, losses, l2s = jax.vmap(
+                train_unit, in_axes=(None, 0, 0))(params[0], bx, by)
+        else:
+            deltas, losses, l2s = jax.vmap(train_unit)(params[row_cell],
+                                                       bx, by)
 
     # --- straggler scatter into the cache, then gather ---------------
-    if faulty:
-        # injected corruption: one IEEE fp32 multiply per delta row —
-        # before the scatter, so cached straggler rows carry the fault too
-        fscale = floats[2 * g_b:2 * g_b + r_b]
-        deltas = deltas * fscale[:, None]
-    # scatter FIRST so the donated cache updates in place (a gather
-    # before the scatter would force XLA to copy the whole buffer);
-    # this round's scatter slots are disjoint from this round's landing
-    # slots because the pipeline quarantines freed slots for one round
-    cache = cache.at[scat_slot].set(deltas)
+    with jax.named_scope("cache"):
+        if faulty:
+            # injected corruption: one IEEE fp32 multiply per delta row —
+            # before the scatter, so cached straggler rows carry the fault too
+            fscale = floats[2 * g_b:2 * g_b + r_b]
+            deltas = deltas * fscale[:, None]
+        # scatter FIRST so the donated cache updates in place (a gather
+        # before the scatter would force XLA to copy the whole buffer);
+        # this round's scatter slots are disjoint from this round's landing
+        # slots because the pipeline quarantines freed slots for one round
+        cache = cache.at[scat_slot].set(deltas)
 
-    # fresh columns from this round's delta rows, stale columns from
-    # the cache slots; same per-cell row multiset as the per-stage
-    # path's (fresh + stale, zero-padded) stack
-    uf, us = deltas[fr_idx], cache[sl_idx]
-    if p_axis is not None:
-        # every operand column is owned by exactly one p-shard (the one
-        # holding its delta row / cache slot): zero the rest and let one
-        # psum reconstruct the full operand — bit-identical to the
-        # unsharded gather, since each element sums one non-zero
-        # contributor with exact zeros
-        uf = jnp.where(agg_mask[:, :nf_b, None], uf, 0.0)
-        us = jnp.where(agg_mask[:, nf_b:, None], us, 0.0)
-        u = jax.lax.psum(jnp.concatenate([uf, us], axis=1), p_axis)
-    else:
-        if not all_valid:
-            # bucket_pad's exact zeros in the padding columns
-            uf = jnp.where(agg_valid[:, :nf_b, None], uf, 0.0)
-            us = jnp.where(agg_valid[:, nf_b:, None], us, 0.0)
-        u = jnp.concatenate([uf, us], axis=1)
-
-    if attack is not None:
-        # coordinated attack: rewrite the attacker rows of the post-psum
-        # operand (pre-lane, pre-screen — the lane and the guard both see
-        # what the server would see)
-        atk_kind, atk_scale, atk_z = attack
-        u = apply_attack(u, agg_att, agg_valid, kind=atk_kind,
-                         scale=atk_scale, z=atk_z)
-
-    if lane:
-        # telemetry lane, device half: row-norm stats over the *pre-screen*
-        # operand, post-psum (p-replicated, no extra collective).  Finite
-        # rows are selected with where() — never multiplied — so one NaN
-        # row cannot poison the finite rows' stats.  Under the persistent
-        # D-blocked layout (``norm_d``) the stats reduce over the true-D
-        # slice: slice-then-reduce is bit-identical to the unpadded layout,
-        # whereas reducing across appended zero columns is not (the SIMD
-        # lane partition of the reduction changes).
-        u_t = u if norm_d is None else u[..., :norm_d]
-        row_fin = jnp.isfinite(u_t).all(axis=-1)
-        norms = jnp.sqrt(jnp.sum(u_t * u_t, axis=-1))
-        ok = agg_valid & row_fin
-        cnt = ok.sum(axis=-1)
-        nonzero = cnt > 0
-        l2_min = jnp.where(nonzero,
-                           jnp.min(jnp.where(ok, norms, jnp.inf), axis=-1),
-                           0.0)
-        l2_max = jnp.where(nonzero,
-                           jnp.max(jnp.where(ok, norms, -jnp.inf), axis=-1),
-                           0.0)
-        l2_mean = jnp.where(
-            nonzero,
-            jnp.sum(jnp.where(ok, norms, 0.0), axis=-1)
-            / jnp.maximum(cnt, 1).astype(jnp.float32), 0.0)
-        lane_nonfin = (agg_valid & ~row_fin).sum(axis=-1)
-
-    # --- guard screening + robust mask step (static: plain programs
-    # are untouched) --------------------------------------------------
-    zeros_g = jnp.zeros(g_b, jnp.int32)
-    n_nf = n_out = rrej = rtrim = zeros_g
-    if guard is not None:
-        clip_g, mult_g, quorum_g = guard
-        u, v2, n_nf, n_out, _ = agg.screen_rows(u, agg_valid, clip=clip_g,
-                                                reject_mult=mult_g,
-                                                norm_d=norm_d)
-        agg_valid = v2
-    robust_coord = robust is not None and robust[0] in COORD_KINDS
-    if robust is not None and not robust_coord:
-        # mask-style robust kinds shrink the survivor mask before the
-        # SAA weights pass (repro.robust.aggregators._robust_cell order:
-        # attack -> guard screen -> robust mask -> weights)
-        if robust[0] in ("krum", "multi_krum"):
-            sel = jax.vmap(functools.partial(
-                krum_select, f=robust[1], m=robust[2]))(u, agg_valid)
-            rrej = (agg_valid & ~sel).sum(axis=-1).astype(jnp.int32)
-            agg_valid = sel
-        else:                                        # norm_median_clip
-            _, clip_r, mult_r = robust
-            u, v2, nf2, out2, ncl2 = agg.screen_rows(
-                u, agg_valid, clip=clip_r, reject_mult=mult_r)
-            rrej, rtrim, agg_valid = nf2 + out2, ncl2, v2
-    survivors = agg_valid.sum(axis=-1).astype(jnp.int32)
-    has_eff = (has_g & (survivors >= quorum_g) if guard is not None
-               else has_g)
-
-    # --- SAA weights + aggregate + server apply ----------------------
-    rows_old = params[agg_cell]                       # (G, D)
-    # robust/attacked programs always take the jnp weights path for the
-    # SAA part; use_kernel then only routes the coordinate-wise trim
-    # through the trimmed_agg kernel (one cross-substrate story)
-    saa_kernel = use_kernel and attack is None and robust is None
-    if saa_kernel:
-        from repro.kernels.staleness_agg.staleness_agg import (
-            D_BLK, sweep_fused_staleness_apply,
-            sweep_fused_staleness_aggregate)
-        d = u.shape[-1]
-        pad = (-d) % D_BLK
-        up = jnp.pad(u, ((0, 0), (0, 0), (0, pad)))
-        if yogi:
-            agg_out, _ = sweep_fused_staleness_aggregate(
-                up, agg_fresh, agg_tau, beta_g, agg_valid,
-                rule=kernel_rule)
-            agg_out = agg_out[:, :d]
+        # fresh columns from this round's delta rows, stale columns from
+        # the cache slots; same per-cell row multiset as the per-stage
+        # path's (fresh + stale, zero-padded) stack
+        uf, us = deltas[fr_idx], cache[sl_idx]
+        if p_axis is not None:
+            # every operand column is owned by exactly one p-shard (the one
+            # holding its delta row / cache slot): zero the rest and let one
+            # psum reconstruct the full operand — bit-identical to the
+            # unsharded gather, since each element sums one non-zero
+            # contributor with exact zeros
+            uf = jnp.where(agg_mask[:, :nf_b, None], uf, 0.0)
+            us = jnp.where(agg_mask[:, nf_b:, None], us, 0.0)
+            u = jax.lax.psum(jnp.concatenate([uf, us], axis=1), p_axis)
         else:
-            scal = jnp.stack([beta_g, lr_g], axis=1)
-            new_rows, _ = sweep_fused_staleness_apply(
-                jnp.pad(rows_old, ((0, 0), (0, pad))), up, agg_fresh,
-                agg_tau, agg_valid, scal, rule=kernel_rule)
-            new_rows = new_rows[:, :d]
-    elif robust_coord:
-        # robust-of-weighted: per-coordinate trimmed mean of the SAA-
-        # weighted rows (trimmed_weighted_aggregate's formula, vmapped)
-        median = robust[0] == "coord_median"
-        tk = 0 if median else robust[1]
-        if use_kernel:
-            from repro.kernels.trimmed_agg import ops as tops
-            y, cc = jax.vmap(weighted_rows)(u, agg_fresh, agg_tau,
-                                            agg_valid, beta_g, rule_id)
-            k_half = jnp.maximum((cc - 1) // 2, 0)
-            k_eff = (k_half if median
-                     else jnp.minimum(jnp.int32(tk), k_half))
-            agg_out = tops.sweep_trimmed_aggregate(y, k_eff, cc)
-            agg_out = jnp.where((cc > 0)[:, None], agg_out, 0.0)
-            rtrim = jnp.where(cc > 0, 2 * k_eff, 0)
+            if not all_valid:
+                # bucket_pad's exact zeros in the padding columns
+                uf = jnp.where(agg_valid[:, :nf_b, None], uf, 0.0)
+                us = jnp.where(agg_valid[:, nf_b:, None], us, 0.0)
+            u = jnp.concatenate([uf, us], axis=1)
+
+    with jax.named_scope("aggregate"):
+        if attack is not None:
+            # coordinated attack: rewrite the attacker rows of the post-psum
+            # operand (pre-lane, pre-screen — the lane and the guard both see
+            # what the server would see)
+            atk_kind, atk_scale, atk_z = attack
+            u = apply_attack(u, agg_att, agg_valid, kind=atk_kind,
+                             scale=atk_scale, z=atk_z)
+
+        if lane:
+            # telemetry lane, device half: row-norm stats over the
+            # *pre-screen* operand, post-psum (p-replicated, no extra
+            # collective).  Finite rows are selected with where() — never
+            # multiplied — so one NaN row cannot poison the finite rows'
+            # stats.  Under the persistent D-blocked layout (``norm_d``) the
+            # stats reduce over the true-D slice: slice-then-reduce is
+            # bit-identical to the unpadded layout, whereas reducing across
+            # appended zero columns is not (the SIMD lane partition of the
+            # reduction changes).
+            u_t = u if norm_d is None else u[..., :norm_d]
+            row_fin = jnp.isfinite(u_t).all(axis=-1)
+            norms = jnp.sqrt(jnp.sum(u_t * u_t, axis=-1))
+            ok = agg_valid & row_fin
+            cnt = ok.sum(axis=-1)
+            nonzero = cnt > 0
+            l2_min = jnp.where(
+                nonzero, jnp.min(jnp.where(ok, norms, jnp.inf), axis=-1), 0.0)
+            l2_max = jnp.where(
+                nonzero, jnp.max(jnp.where(ok, norms, -jnp.inf), axis=-1),
+                0.0)
+            l2_mean = jnp.where(
+                nonzero,
+                jnp.sum(jnp.where(ok, norms, 0.0), axis=-1)
+                / jnp.maximum(cnt, 1).astype(jnp.float32), 0.0)
+            lane_nonfin = (agg_valid & ~row_fin).sum(axis=-1)
+
+        # --- guard screening + robust mask step (static: plain programs
+        # are untouched) --------------------------------------------------
+        zeros_g = jnp.zeros(g_b, jnp.int32)
+        n_nf = n_out = rrej = rtrim = zeros_g
+        if guard is not None:
+            clip_g, mult_g, quorum_g = guard
+            u, v2, n_nf, n_out, _ = agg.screen_rows(u, agg_valid, clip=clip_g,
+                                                    reject_mult=mult_g,
+                                                    norm_d=norm_d)
+            agg_valid = v2
+        robust_coord = robust is not None and robust[0] in COORD_KINDS
+        if robust is not None and not robust_coord:
+            # mask-style robust kinds shrink the survivor mask before the
+            # SAA weights pass (repro.robust.aggregators._robust_cell order:
+            # attack -> guard screen -> robust mask -> weights)
+            if robust[0] in ("krum", "multi_krum"):
+                sel = jax.vmap(functools.partial(
+                    krum_select, f=robust[1], m=robust[2]))(u, agg_valid)
+                rrej = (agg_valid & ~sel).sum(axis=-1).astype(jnp.int32)
+                agg_valid = sel
+            else:                                        # norm_median_clip
+                _, clip_r, mult_r = robust
+                u, v2, nf2, out2, ncl2 = agg.screen_rows(
+                    u, agg_valid, clip=clip_r, reject_mult=mult_r)
+                rrej, rtrim, agg_valid = nf2 + out2, ncl2, v2
+        survivors = agg_valid.sum(axis=-1).astype(jnp.int32)
+        has_eff = (has_g & (survivors >= quorum_g) if guard is not None
+                   else has_g)
+
+        # --- SAA weights + aggregate + server apply ----------------------
+        rows_old = params[agg_cell]                       # (G, D)
+        # robust/attacked programs always take the jnp weights path for the
+        # SAA part; use_kernel then only routes the coordinate-wise trim
+        # through the trimmed_agg kernel (one cross-substrate story)
+        saa_kernel = use_kernel and attack is None and robust is None
+        if saa_kernel:
+            from repro.kernels.staleness_agg.staleness_agg import (
+                D_BLK, sweep_fused_staleness_apply,
+                sweep_fused_staleness_aggregate)
+            d = u.shape[-1]
+            pad = (-d) % D_BLK
+            up = jnp.pad(u, ((0, 0), (0, 0), (0, pad)))
+            if yogi:
+                agg_out, _ = sweep_fused_staleness_aggregate(
+                    up, agg_fresh, agg_tau, beta_g, agg_valid,
+                    rule=kernel_rule)
+                agg_out = agg_out[:, :d]
+            else:
+                scal = jnp.stack([beta_g, lr_g], axis=1)
+                new_rows, _ = sweep_fused_staleness_apply(
+                    jnp.pad(rows_old, ((0, 0), (0, pad))), up, agg_fresh,
+                    agg_tau, agg_valid, scal, rule=kernel_rule)
+                new_rows = new_rows[:, :d]
+        elif robust_coord:
+            # robust-of-weighted: per-coordinate trimmed mean of the SAA-
+            # weighted rows (trimmed_weighted_aggregate's formula, vmapped)
+            median = robust[0] == "coord_median"
+            tk = 0 if median else robust[1]
+            if use_kernel:
+                from repro.kernels.trimmed_agg import ops as tops
+                y, cc = jax.vmap(weighted_rows)(u, agg_fresh, agg_tau,
+                                                agg_valid, beta_g, rule_id)
+                k_half = jnp.maximum((cc - 1) // 2, 0)
+                k_eff = (k_half if median
+                         else jnp.minimum(jnp.int32(tk), k_half))
+                agg_out = tops.sweep_trimmed_aggregate(y, k_eff, cc)
+                agg_out = jnp.where((cc > 0)[:, None], agg_out, 0.0)
+                rtrim = jnp.where(cc > 0, 2 * k_eff, 0)
+            else:
+                agg_out, rtrim = jax.vmap(functools.partial(
+                    trimmed_weighted_aggregate, trim_k=tk, median=median))(
+                    u, agg_fresh, agg_tau, agg_valid, beta_g, rule_id)
+        elif ns_b == 0:
+            # no stale rows anywhere this round: Eq. 2 degenerates to the
+            # fresh average, so skip the deviation pass entirely.  The
+            # weight vector is bit-identical to the general path's (fresh
+            # rows weigh 1, padding weighs 0, same normalization).  Under a
+            # guard or a mask-style robust kind, rejected fresh rows must
+            # weigh 0 too (agg_valid is the post-screen survivor mask;
+            # without faults it covers every fresh column, so the bits are
+            # unchanged).
+            w = ((agg_fresh & agg_valid).astype(jnp.float32)
+                 if guard is not None or robust is not None
+                 else agg_fresh.astype(jnp.float32))
+            w = w / jnp.maximum(w.sum(axis=1, keepdims=True), EPS)
+            agg_out = jax.vmap(aggregate_updates)(u, w)
         else:
-            agg_out, rtrim = jax.vmap(functools.partial(
-                trimmed_weighted_aggregate, trim_k=tk, median=median))(
+            agg_out, _ = jax.vmap(weights_and_aggregate_by_id)(
                 u, agg_fresh, agg_tau, agg_valid, beta_g, rule_id)
-    elif ns_b == 0:
-        # no stale rows anywhere this round: Eq. 2 degenerates to the
-        # fresh average, so skip the deviation pass entirely.  The
-        # weight vector is bit-identical to the general path's (fresh
-        # rows weigh 1, padding weighs 0, same normalization).  Under a
-        # guard or a mask-style robust kind, rejected fresh rows must
-        # weigh 0 too (agg_valid is the post-screen survivor mask;
-        # without faults it covers every fresh column, so the bits are
-        # unchanged).
-        w = ((agg_fresh & agg_valid).astype(jnp.float32)
-             if guard is not None or robust is not None
-             else agg_fresh.astype(jnp.float32))
-        w = w / jnp.maximum(w.sum(axis=1, keepdims=True), EPS)
-        agg_out = jax.vmap(aggregate_updates)(u, w)
-    else:
-        agg_out, _ = jax.vmap(weights_and_aggregate_by_id)(
-            u, agg_fresh, agg_tau, agg_valid, beta_g, rule_id)
 
     # --- stats block + lane assembly ---------------------------------
     if guard is not None or robust is not None:
@@ -506,20 +525,21 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     else:
         # zero-width block keeps the program signature uniform at no cost
         lanes = jnp.zeros((g_b, 0), jnp.float32)
-    if yogi:
-        state_rows = jax.tree.map(lambda s: s[agg_cell], opt_state)
-        new_rows, new_state = jax.vmap(yogi_apply_flat)(
-            rows_old, agg_out, state_rows)
-        keep = lambda new, old: jnp.where(
-            has_eff.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
-        opt_state = jax.tree.map(
-            lambda s, ns, os: s.at[agg_cell].set(keep(ns, os)),
-            opt_state, new_state, state_rows)
-    elif not saa_kernel:
-        new_rows = rows_old + lr_g[:, None] * agg_out
-    # quorum failures (has_eff < has_g) carry the old rows unchanged
-    new_rows = jnp.where(has_eff[:, None], new_rows, rows_old)
-    params = params.at[agg_cell].set(new_rows)
+    with jax.named_scope("apply"):
+        if yogi:
+            state_rows = jax.tree.map(lambda s: s[agg_cell], opt_state)
+            new_rows, new_state = jax.vmap(yogi_apply_flat)(
+                rows_old, agg_out, state_rows)
+            keep = lambda new, old: jnp.where(
+                has_eff.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
+            opt_state = jax.tree.map(
+                lambda s, ns, os: s.at[agg_cell].set(keep(ns, os)),
+                opt_state, new_state, state_rows)
+        elif not saa_kernel:
+            new_rows = rows_old + lr_g[:, None] * agg_out
+        # quorum failures (has_eff < has_g) carry the old rows unchanged
+        new_rows = jnp.where(has_eff[:, None], new_rows, rows_old)
+        params = params.at[agg_cell].set(new_rows)
     return params, cache, opt_state, losses, l2s, gstats, lanes
 
 
@@ -648,8 +668,9 @@ def _eval_program(spec, evaluate=ln.evaluate):
     def f(params, packed, x_u, y_u):
         l_b = packed.shape[0] // 2
         eval_idx, te_idx = packed[:l_b], packed[l_b:]
-        return jax.vmap(ev, in_axes=(0, 0, None, None))(
-            params[eval_idx], te_idx, x_u, y_u)
+        with jax.named_scope("eval"):
+            return jax.vmap(ev, in_axes=(0, 0, None, None))(
+                params[eval_idx], te_idx, x_u, y_u)
 
     return jax.jit(f)
 
@@ -696,6 +717,22 @@ class RoundPipeline:
                  start_round: int = 0, telemetry=None,
                  labels: Optional[Sequence[str]] = None):
         assert len(sims) >= 1
+        # every pipeline has a telemetry session; the directory-less
+        # default costs ~nothing (null spans, no writers) but still backs
+        # PipelineStats with a live registry
+        self.telemetry = (telemetry if telemetry is not None
+                          else TelemetrySession())
+        for sim in sims:
+            sim._hand_over_build(self.telemetry)
+        with self.telemetry.span("upload", cells=len(sims)):
+            self._setup(sims, progress, mesh, checkpoint_path,
+                        checkpoint_every, checkpoint_wrap, start_round,
+                        labels)
+
+    def _setup(self, sims, progress, mesh, checkpoint_path, checkpoint_every,
+               checkpoint_wrap, start_round, labels) -> None:
+        """Host state, device buffers (parameters, stale cache, optimizer
+        state, datasets) and the compiled programs of a batch."""
         self.sims = list(sims)
         self.progress = progress
         cfg0 = sims[0].cfg
@@ -710,11 +747,6 @@ class RoundPipeline:
             assert pipeline_key(sim.cfg) == pipeline_key(cfg0), \
                 "incompatible Simulators in one pipeline batch"
         self.cfg0 = cfg0
-        # every pipeline has a telemetry session; the directory-less
-        # default costs ~nothing (null spans, no writers) but still backs
-        # PipelineStats with a live registry
-        self.telemetry = (telemetry if telemetry is not None
-                          else TelemetrySession())
         self._labels = (list(labels) if labels is not None
                         else [f"sim{i}" for i in range(len(sims))])
         # level >= 2 turns on the in-program round-stats lane (static in
@@ -1147,6 +1179,9 @@ class RoundPipeline:
                         and len(w0.scheds[i].landing) == ns_b
                         for i in groups0))
         shapes = (r_b, tb, g_b, nf_b, ns_b, all_valid)
+        trained = sum(len(w.rowq) for w in works)
+        self.stats.trained_rows += trained
+        self.stats.pad_rows += len(works) * nflat * r_b - trained
 
         # a faulted batch appends the per-row corruption multipliers to the
         # floats buffer (static layout — pipeline_key keeps faulted and
@@ -1306,22 +1341,9 @@ class RoundPipeline:
         ints_all = np.stack(chunks)        # already int32 throughout
         return ints_all, floats_all, shapes, offs, gmaps
 
-    def _run_chunk(self, rounds) -> None:
-        """Preschedule up to K rounds, dispatch them as one scan program,
-        then run the post-dispatch tail (Oort feedback, eval fill, early
-        stop, shard repack) for the chunk."""
-        works = []
-        with self.telemetry.span("schedule", rounds=len(rounds)):
-            for r in rounds:
-                w = self._preschedule(r)
-                if w is not None:
-                    works.append(w)
-        if not works:
-            return
-        sims = self.sims
-        with self.telemetry.span("pack", rounds=len(works)):
-            ints, floats, shapes, offs, gmaps = self._materialize(works)
-
+    def _put(self, ints, floats):
+        """Upload a chunk's packed buffers; returns them on the device with
+        the cache rows the dispatch takes."""
         if self.mesh is None:
             dev_ints, dev_floats = jax.device_put(
                 (ints[:, 0], floats[:, 0]))
@@ -1348,10 +1370,31 @@ class RoundPipeline:
             dev_ints = jax.device_put(ints, self._chunk_spec)
             dev_floats = jax.device_put(floats, self._chunk_spec)
             cache_rows = self.cache_rows
-        self.stats.h2d_bytes += ints.nbytes + floats.nbytes
-        self.stats.dispatches["round"] += 1
-        self.stats.rounds += len(works)
-        with self.telemetry.span("dispatch", rounds=len(works)):
+        return dev_ints, dev_floats, cache_rows
+
+    def _run_chunk(self, rounds) -> None:
+        """Preschedule up to K rounds, dispatch them as one scan program,
+        then run the post-dispatch tail (Oort feedback, eval fill, early
+        stop, shard repack) for the chunk."""
+        works = []
+        with self.telemetry.span("schedule", rounds=len(rounds)):
+            for r in rounds:
+                w = self._preschedule(r)
+                if w is not None:
+                    works.append(w)
+        if not works:
+            return
+        sims = self.sims
+        with self.telemetry.span("pack", rounds=len(works)):
+            ints, floats, shapes, offs, gmaps = self._materialize(works)
+
+        with self.telemetry.span("put", rounds=len(works)):
+            dev_ints, dev_floats, cache_rows = self._put(ints, floats)
+            self.stats.h2d_bytes += ints.nbytes + floats.nbytes
+            self.stats.dispatches["round"] += 1
+            self.stats.rounds += len(works)
+        with self.telemetry.span("dispatch", rounds=len(works)), \
+                self.telemetry.tracer.step("round", works[0].r):
             (params, cache_rows, self.opt_state, _losses, l2s, gstats,
              lanes) = self._prog(self.params, cache_rows, self.opt_state,
                                  self.x_tr, self.y_tr, dev_ints, dev_floats,
@@ -1648,6 +1691,10 @@ class RoundPipeline:
         """Write the device state back to the Simulators and finalize each.
         After this the pipeline's donated-buffer chain ends; the returned
         Accountings are the same objects ``Simulator.run`` yields."""
+        with self.telemetry.span("finalize", cells=len(self.sims)):
+            return self._write_back()
+
+    def _write_back(self):
         accts = []
         if self.mesh is None:
             for i, sim in enumerate(self.sims):

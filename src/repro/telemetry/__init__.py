@@ -13,7 +13,8 @@ lane at level 2 adds no collective — it is computed post-``psum`` and
 fetched only at existing chunk boundaries.
 """
 from .registry import Counter, CounterView, Gauge, Histogram, MetricsRegistry
-from .schema import (DISPATCH_KINDS, GUARD_COUNTERS, LANE_FIELDS, LANE_WIDTH,
+from .schema import (COMPILE_COUNTERS, DISPATCH_KINDS, GUARD_COUNTERS,
+                     LANE_FIELDS, LANE_WIDTH,
                      N_LANE_HOST, PIPELINE_COUNTERS, ROUND_EVENT_KEYS,
                      SPAN_NAMES)
 from .session import TelemetrySession
@@ -22,7 +23,8 @@ from .export import JsonlWriter, dumps_event, write_prometheus
 
 __all__ = [
     "Counter", "CounterView", "Gauge", "Histogram", "MetricsRegistry",
-    "DISPATCH_KINDS", "GUARD_COUNTERS", "LANE_FIELDS", "LANE_WIDTH",
+    "COMPILE_COUNTERS", "DISPATCH_KINDS", "GUARD_COUNTERS", "LANE_FIELDS",
+    "LANE_WIDTH",
     "N_LANE_HOST", "PIPELINE_COUNTERS", "ROUND_EVENT_KEYS", "SPAN_NAMES",
     "TelemetrySession", "Tracer", "JsonlWriter", "dumps_event",
     "write_prometheus",

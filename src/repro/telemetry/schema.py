@@ -87,17 +87,33 @@ PIPELINE_COUNTERS = (
     "pipeline_init_h2d_bytes",
     "pipeline_cross_shard_landings",
     "pipeline_feedback_fetches",
+    "pipeline_trained_rows",    # packed rows that train (survivors)
+    "pipeline_pad_rows",        # padding rows of the chosen shape bucket
+)
+# Compile work seen while an enabled session is open
+# (``repro.telemetry.compile``).
+COMPILE_COUNTERS = (
+    "compile_programs_lowered",     # jaxpr -> MLIR module lowerings
+    "compile_backend_compiles",     # backend compiles, cache loads included
+    "compile_cache_hits",           # persistent compilation cache hits
+    "compile_cache_misses",         # ... and writes after a miss
 )
 DISPATCH_KINDS = ("round", "eval", "cache_grow", "repack")
 
 # ---------------------------------------------------------------------------
 # Host-side tracer span names (Chrome trace-event JSON, Perfetto-loadable).
 SPAN_NAMES = (
+    "build",        # Simulator construction (recorded after the fact)
+    "upload",       # RoundPipeline construction: device_puts of state+data
     "schedule",     # host prescheduling of a chunk of rounds
     "pack",         # packing dispatch int32/fp32 buffers
-    "dispatch",     # device_put + the fused round program
+    "put",          # device_put of the packed buffers
+    "dispatch",     # the fused round program (holds the profiler's
+                    # StepTraceAnnotation "round", step = first round)
     "fetch",        # device_get of gstats / lane / l2s + attribution
     "eval",         # deferred eval fill + early-stop bookkeeping
     "repack",       # early-stop sweep-bucket repacking
     "checkpoint",   # snapshot write
+    "finalize",     # device state written back, Simulator._finalize
+    "compile",      # trace / lower / backend compile (jax.monitoring)
 )

@@ -22,11 +22,10 @@ byte-identical round log of an uninterrupted run.
 """
 from __future__ import annotations
 
-import contextlib
 import os
-import time
 from typing import Dict, Optional
 
+from . import compile as compile_spans
 from .export import JsonlWriter, write_prometheus
 from .registry import MetricsRegistry
 from .schema import LANE_FIELDS, LANE_INT_FIELDS
@@ -50,23 +49,36 @@ class TelemetrySession:
             self._rounds = JsonlWriter(os.path.join(dir, "rounds.jsonl"))
             self._events = JsonlWriter(os.path.join(dir, "events.jsonl"))
         self._closed = False
+        self._span_hists: Dict[str, object] = {}
+        if tracer.enabled:
+            compile_spans.watch(self)
 
     # -- spans ---------------------------------------------------------------
     def span(self, name: str, **args):
-        if not self.tracer.enabled:     # dir-less sessions: null span, no cost
-            return self.tracer.span(name, **args)
-        return self._timed_span(name, args)
+        """Trace span; an enabled one also observes its wall duration (the
+        tracer's own reading) into the registry's ``span_seconds_<name>``
+        histogram (metrics.prom only — timings are wall-clock and stay out
+        of the deterministic round log).  A disabled tracer's: null span."""
+        sp = self.tracer.span(name, **args)
+        if self.tracer.enabled:
+            sp.histogram = self._histogram(name)
+        return sp
 
-    @contextlib.contextmanager
-    def _timed_span(self, name: str, args: dict):
-        """Trace span + wall-duration sample into the registry's
-        ``span_seconds_<name>`` histogram (metrics.prom only — timings are
-        wall-clock and stay out of the deterministic round log)."""
-        t0 = time.perf_counter()
-        with self.tracer.span(name, **args):
-            yield
-        self.registry.histogram(f"span_seconds_{name}").observe(
-            time.perf_counter() - t0)
+    def _histogram(self, name: str):
+        hist = self._span_hists.get(name)
+        if hist is None:
+            hist = self._span_hists[name] = self.registry.histogram(
+                f"span_seconds_{name}")
+        return hist
+
+    def complete(self, name: str, start_ns: int, end_ns: int,
+                 **args) -> None:
+        """A span whose bounds are known only afterwards
+        (``time.perf_counter_ns``), recorded like ``span``'s."""
+        if not self.tracer.enabled:
+            return
+        self.tracer.complete(name, start_ns, end_ns, **args)
+        self._histogram(name).observe((end_ns - start_ns) / 1e9)
 
     # -- events --------------------------------------------------------------
     def round_event(self, cell: str, lane_row, rec) -> Dict[str, object]:
@@ -150,6 +162,7 @@ class TelemetrySession:
         if self._closed:
             return
         self._closed = True
+        compile_spans.unwatch(self)
         if self._rounds is not None:
             self._rounds.close()
         if self._events is not None:
