@@ -16,8 +16,10 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 @pytest.fixture
 def cache_dir_restored():
     was = jax.config.jax_compilation_cache_dir
+    meta = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", meta)
 
 
 def test_env_dir_is_the_only_cache_dir(monkeypatch, tmp_path,
@@ -36,6 +38,20 @@ def test_unset_env_uses_the_fixed_checkout_dir(monkeypatch,
     assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
     ignored = (REPO / ".gitignore").read_text().splitlines()
     assert "/.jax_cache/" in ignored
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_cache_key_holds_the_program_metadata(monkeypatch, tmp_path,
+                                              cache_dir_restored, env_dir):
+    """Named scopes live in metadata only: without it in the key, a cached
+    executable of another version would carry that version's op names."""
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_library_import_leaves_the_cache_alone():
