@@ -22,7 +22,8 @@ Registered strategies (``python -m repro.sweeps --list-selectors``):
   contribution  decayed contribution ranking + fairness floor
 """
 from repro.selection.base import (BuildContext, Knob, LearnerView,  # noqa: F401
-                                  Selector, SelectorSpec, class_factory)
+                                  Selector, SelectorSpec, class_factory,
+                                  views_to_arrays)
 from repro.selection.registry import (SELECTOR_TABLE,  # noqa: F401
                                       build_selector, describe_selectors,
                                       normalize_selector_params,
@@ -47,7 +48,7 @@ __all__ = [
     "BuildContext", "Knob", "LearnerView", "Selector", "SelectorSpec",
     "SELECTOR_TABLE", "SELECTORS", "build_selector", "class_factory",
     "describe_selectors", "normalize_selector_params", "register_selector",
-    "selector_key",
+    "selector_key", "views_to_arrays",
     "RandomSelector", "OortSelector", "PrioritySelector", "SafaSelector",
     "FlipsSelector", "UcbSelector", "ContributionSelector",
 ]

@@ -42,7 +42,11 @@ from repro.core import registry as _registry
 
 @dataclasses.dataclass
 class LearnerView:
-    """What the server may know about a checked-in learner."""
+    """What the server may know about a checked-in learner.  The array form
+    (``Selector.select_arrays``) carries the id, ``availability_prob`` and
+    ``est_duration``; the built-in policies keep utilities and participation
+    in their own state.  Of ``last_stat_util`` only Oort's ``select`` reads
+    it, as an unexplored learner's utility (0.0 in the array form)."""
     learner_id: int
     availability_prob: float = 1.0   # learner-reported P(available in [mu, 2mu])
     last_stat_util: float = 0.0      # |B_i| * sqrt(mean loss^2) from last participation
@@ -50,18 +54,59 @@ class LearnerView:
     explored: bool = False           # has participated before
 
 
+def views_to_arrays(checked_in: Sequence[LearnerView]):
+    """(ids, probs, durs) of a list of views, in the views' order: the
+    ``select_arrays`` form of a ``select`` call."""
+    ids = np.array([v.learner_id for v in checked_in], np.int64)
+    probs = np.array([v.availability_prob for v in checked_in], np.float64)
+    durs = np.array([v.est_duration for v in checked_in], np.float64)
+    return ids, probs, durs
+
+
+def grown(a: np.ndarray, n: int, fill) -> np.ndarray:
+    """``a`` extended with ``fill`` to at least ``n`` entries (doubling), for
+    per-learner state indexed by learner id."""
+    if n <= len(a):
+        return a
+    out = np.full(max(n, 2 * len(a)), fill, a.dtype)
+    out[:len(a)] = a
+    return out
+
+
 class Selector:
+    """Selection policy.  The engine hands a policy the round's check-in in
+    one of two forms, by ``needs_views``:
+
+    - ``select_arrays(round_idx, ids, probs, durs, n_target, rng)`` for
+      ``needs_views = True``: three aligned arrays in ascending id order —
+      learner ids, forecast availability probabilities and estimated round
+      durations.  The default builds ``LearnerView`` objects and calls
+      ``select``, so a policy written against views keeps working; the
+      built-in view policies (priority, oort) implement it on the arrays.
+    - ``select_ids(round_idx, ids, n_target, rng)`` for ``needs_views =
+      False``: the ids alone; the engine then skips the forecaster window
+      queries.  The queries are pure reads, so skipping them never changes
+      forecaster state or the RNG stream.
+
+    ``select(views)`` is the list-of-views entry for older callers; a policy
+    that implements ``select_arrays`` answers it by unpacking the views with
+    ``views_to_arrays``.
+    """
     name = "base"
-    # Selectors that ignore availability forecasts / utilities set this False
-    # and implement ``select_ids``; the engine then skips building LearnerViews
-    # (and the forecaster window queries behind them) on the hot path.  The
-    # queries are pure reads, so skipping them never changes forecaster state
-    # or the RNG stream — selection is bit-identical either way.
     needs_views = True
 
     def select(self, round_idx: int, checked_in: Sequence[LearnerView],
                n_target: int, rng: np.random.Generator) -> List[int]:
         raise NotImplementedError
+
+    def select_arrays(self, round_idx: int, ids: np.ndarray,
+                      probs: np.ndarray, durs: np.ndarray, n_target: int,
+                      rng: np.random.Generator) -> List[int]:
+        """For a policy that implements ``select`` alone: the check-in as
+        ``LearnerView``s, built as the engine built them for ``select``."""
+        views = [LearnerView(lid, availability_prob=float(p), est_duration=d)
+                 for lid, p, d in zip(ids, probs, durs)]
+        return self.select(round_idx, views, n_target, rng)
 
     def select_ids(self, round_idx: int, ids, n_target: int,
                    rng: np.random.Generator) -> List[int]:

@@ -1,14 +1,16 @@
 """RELAY's IPS (paper Alg. 1): least-available-first priority selection.
 
-Ported verbatim from the pre-zoo ``repro.core.selection`` — the jitter
-draw (`rng.random(len(eligible))`) is part of the RNG-stream parity
-contract.
+Ported from the pre-zoo ``repro.core.selection`` onto arrays: the same
+eligibility, the same single jitter draw (`rng.random(len(eligible))`, part
+of the RNG-stream parity contract) and the same (probability, jitter)
+order, so the decisions are bit-identical to the list-of-views original.
 """
 from __future__ import annotations
 
-from typing import Dict
+import numpy as np
 
-from repro.selection.base import Knob, Selector, SelectorSpec, class_factory
+from repro.selection.base import (Knob, Selector, SelectorSpec, class_factory,
+                                  grown, views_to_arrays)
 from repro.selection.registry import register_selector
 
 
@@ -20,21 +22,35 @@ class PrioritySelector(Selector):
 
     def __init__(self, holdoff: int = 5):
         self.holdoff = holdoff
-        self._held_until: Dict[int, int] = {}
+        # round until which each learner id is held off; -1 = never chosen
+        self._held_until = np.full(0, -1, np.int64)
+
+    def __setstate__(self, state):
+        # checkpoints written before the array form hold a {lid: round} dict
+        held = state["_held_until"]
+        if isinstance(held, dict):
+            arr = np.full(max(held, default=-1) + 1, -1, np.int64)
+            arr[list(held)] = list(held.values())
+            state["_held_until"] = arr
+        self.__dict__.update(state)
 
     def select(self, round_idx, checked_in, n_target, rng):
-        eligible = [v for v in checked_in
-                    if self._held_until.get(v.learner_id, -1) < round_idx]
-        if not eligible:
-            eligible = list(checked_in)
-        # ascending availability; random shuffle breaks ties (Alg. 1)
-        jitter = rng.random(len(eligible))
-        order = sorted(range(len(eligible)),
-                       key=lambda i: (eligible[i].availability_prob, jitter[i]))
-        chosen = [eligible[i].learner_id for i in order[:n_target]]
-        for lid in chosen:
-            self._held_until[lid] = round_idx + self.holdoff
-        return chosen
+        return self.select_arrays(round_idx, *views_to_arrays(checked_in),
+                                  n_target, rng)
+
+    def select_arrays(self, round_idx, ids, probs, durs, n_target, rng):
+        self._held_until = grown(self._held_until,
+                                 int(ids.max(initial=-1)) + 1, -1)
+        eligible = self._held_until[ids] < round_idx
+        if not eligible.any():
+            eligible[:] = True
+        ids, probs = ids[eligible], probs[eligible]
+        # ascending availability; random shuffle breaks ties (Alg. 1).
+        # lexsort is stable, as sorted() on the (prob, jitter) key was
+        jitter = rng.random(len(ids))
+        chosen = ids[np.lexsort((jitter, probs))[:n_target]]
+        self._held_until[chosen] = round_idx + self.holdoff
+        return chosen.tolist()
 
 
 register_selector(SelectorSpec(

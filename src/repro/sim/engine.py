@@ -79,7 +79,7 @@ from repro.core.aggregation import (fedavg_apply, stale_synchronous_aggregate,
                                     yogi_apply_flat, yogi_init, yogi_init_flat)
 from repro.core.apt import AdaptiveParticipantTarget
 from repro.core.availability import AvailabilityForecaster, ForecasterBank
-from repro.selection import (SELECTOR_TABLE, LearnerView, build_selector,
+from repro.selection import (SELECTOR_TABLE, build_selector,
                              normalize_selector_params)
 from repro.faults.attacks import attack_key
 from repro.robust.aggregators import robust_host_aggregate, robust_key
@@ -489,6 +489,7 @@ class Simulator:
         self.busy_until = np.zeros(cfg.n_learners)  # device busy training/uploading
         self.mu = cfg.deadline  # initial round-duration estimate
         self._t_now = 0.0
+        self.n_checked_in = 0   # learners checked in at the last _begin_round
 
     # ------------------------------------------------------------------
     def _warmup_forecasters(self):
@@ -520,16 +521,16 @@ class Simulator:
         return available
 
     def _views(self, t_now: float, available_ids):
+        """The check-in as a view selector reads it: ids (ascending),
+        forecast P(available in [t+mu, t+2mu]) and round durations."""
+        ids = np.asarray(available_ids, np.int64)
         t0, t1 = t_now + self.mu, t_now + 2 * self.mu
         if self.cfg.fast_path:
-            probs = self.fbank.predict_window_batch(available_ids, t0, t1)
-            return [LearnerView(lid, availability_prob=float(p),
-                                est_duration=self.durations[lid])
-                    for lid, p in zip(available_ids, probs)]
-        return [LearnerView(lid,
-                            availability_prob=self.forecasters[lid].predict_window(t0, t1),
-                            est_duration=self.durations[lid])
-                for lid in available_ids]
+            probs = self.fbank.predict_window_batch(ids, t0, t1)
+        else:
+            probs = np.array([self.forecasters[lid].predict_window(t0, t1)
+                              for lid in available_ids], np.float64)
+        return ids, probs, self.durations[ids]
 
     # ------------------------------------------------------------------
     # Round stages (run() chains them; repro.sweeps.runner drives them in
@@ -547,6 +548,7 @@ class Simulator:
         self._t_now += cfg.selection_window
         t_now = self._t_now
         available = self._available_now(t_now)
+        self.n_checked_in = len(available)     # the census handed to selection
         if not len(available):
             self._t_now += 60.0
             return None
@@ -559,8 +561,8 @@ class Simulator:
         n_sel = (int(np.ceil(n_t * cfg.overcommit))
                  if cfg.setting == "OC" else n_t)
         if self.selector.needs_views:
-            views = self._views(t_now, available)
-            chosen = self.selector.select(r, views, n_sel, self.rng)
+            chosen = self.selector.select_arrays(
+                r, *self._views(t_now, available), n_sel, self.rng)
         else:
             # view-free selectors (random, safa) skip the forecaster window
             # queries — pure reads, so state and RNG streams are untouched

@@ -203,6 +203,10 @@ class PipelineStats:
     pad_rows = property(
         lambda s: s._counter("pad_rows").value,
         lambda s, v: setattr(s._counter("pad_rows"), "value", v))
+    # the census each round hands to selection (its per-round work)
+    checked_in = property(
+        lambda s: s._counter("checked_in").value,
+        lambda s, v: setattr(s._counter("checked_in"), "value", v))
 
     def as_dict(self) -> dict:
         per_round = max(self.rounds, 1)
@@ -223,6 +227,7 @@ class PipelineStats:
             "feedback_fetches": self.feedback_fetches,
             "trained_rows": self.trained_rows,
             "pad_rows": self.pad_rows,
+            "checked_in": self.checked_in,
             "guard": dict(self.guard),
         }
 
@@ -1026,6 +1031,7 @@ class RoundPipeline:
             if self.done[i]:
                 continue
             p = sim._begin_round(r)
+            self.stats.checked_in += sim.n_checked_in
             if p is not None:
                 plans[i] = p
         if not plans:

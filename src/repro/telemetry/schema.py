@@ -89,6 +89,7 @@ PIPELINE_COUNTERS = (
     "pipeline_feedback_fetches",
     "pipeline_trained_rows",    # packed rows that train (survivors)
     "pipeline_pad_rows",        # padding rows of the chosen shape bucket
+    "pipeline_checked_in",      # checked-in learners handed to selection
 )
 # Compile work seen while an enabled session is open
 # (``repro.telemetry.compile``).

@@ -121,11 +121,19 @@ class _LegacyOort:
             self._duration[learner_id] = duration
 
 
-def _views(rng, n):
-    return [LearnerView(learner_id=i,
-                        availability_prob=float(rng.random()),
-                        est_duration=float(10 + 90 * rng.random()))
-            for i in range(n)]
+def _census(rng, n, probs, durs):
+    """One round's check-in as the engine hands it over: a random subset of
+    the population in ascending id order, as arrays and as the equivalent
+    views (probabilities as floats, durations as numpy scalars)."""
+    ids = np.flatnonzero(rng.random(n) < 0.4)
+    views = [LearnerView(lid, availability_prob=float(probs[lid]),
+                         est_duration=durs[lid]) for lid in ids]
+    return ids, probs[ids], durs[ids], views
+
+
+def _held_dict(sel):
+    return {lid: h for lid, h in enumerate(sel._held_until.tolist())
+            if h >= 0}
 
 
 def test_random_ported_bit_identical():
@@ -145,36 +153,192 @@ def test_safa_ported_bit_identical():
     assert new.select_ids(0, ids, 2, np.random.default_rng(0)) == ids
 
 
-def test_priority_ported_bit_identical():
-    legacy, new = _LegacyPriority(), PrioritySelector()
-    setup = np.random.default_rng(7)
-    views = _views(setup, 25)
-    r1 = np.random.default_rng(1)
-    r2 = np.random.default_rng(1)
-    for r in range(20):
-        assert legacy.select(r, views, 6, r1) == new.select(r, views, 6, r2)
-    assert legacy._held_until == new._held_until
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("n", [25, 1000])
+def test_priority_ported_bit_identical(n, seed):
+    """The oracle on views against ``select_arrays`` and the ``select``
+    adapter, over the engine's census of a changing check-in: exact ties in
+    the probabilities, and a round in which every learner is held off."""
+    legacy, new, via_views = (_LegacyPriority(), PrioritySelector(),
+                              PrioritySelector())
+    setup = np.random.default_rng(100 + seed)
+    # a third of the probabilities on a coarse grid: exact ties
+    probs = setup.random(n)
+    probs[: n // 3] = np.round(probs[: n // 3], 1)
+    durs = 10 + 90 * setup.random(n)
+    rngs = [np.random.default_rng(seed) for _ in range(3)]
+    n_target = max(3, n // 40)
+    prev, all_held = [], 0
+    for r in range(30):
+        ids, p, d, views = _census(setup, n, probs, durs)
+        if r == 12:   # only last round's cohort checks in: all held off
+            ids = np.asarray(sorted(prev), np.int64)
+            p, d = probs[ids], durs[ids]
+            views = [LearnerView(lid, availability_prob=float(probs[lid]),
+                                 est_duration=durs[lid]) for lid in ids]
+            all_held += all(legacy._held_until.get(lid, -1) >= r
+                            for lid in ids)
+        a = legacy.select(r, views, n_target, rngs[0])
+        b = new.select_arrays(r, ids, p, d, n_target, rngs[1])
+        c = via_views.select(r, views, n_target, rngs[2])
+        assert a == b == c, r
+        prev = a
+    assert all_held == 1
+    assert legacy._held_until == _held_dict(new) == _held_dict(via_views)
+    assert (rngs[0].bit_generator.state == rngs[1].bit_generator.state
+            == rngs[2].bit_generator.state)
 
 
-def test_oort_ported_bit_identical():
-    legacy, new = _LegacyOort(), OortSelector()
-    setup = np.random.default_rng(11)
-    views = _views(setup, 30)
-    fb = np.random.default_rng(13)
-    r1 = np.random.default_rng(2)
-    r2 = np.random.default_rng(2)
-    for r in range(50):
-        a = legacy.select(r, views, 8, r1)
-        b = new.select(r, views, 8, r2)
-        assert a == b
-        # identical post-round feedback (same utilities, same durations)
+@pytest.mark.parametrize("inputs", ["engine", "float"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("n", [25, 1000])
+def test_oort_ported_bit_identical(n, seed, inputs):
+    """The oracle on views against ``select_arrays`` and the ``select``
+    adapter through exploration, exploitation, the slow-learner penalty and
+    the pacer, with exact ties in utilities and durations.  The pacer's
+    Python sum depends on the inputs' types: ``engine`` gives durations as
+    numpy scalars (as the engine's arrays hold them) and utilities as
+    floats; ``float`` gives every number as an exact float, in the views and
+    in the feedback, which only views can carry, so ``select_arrays`` sits
+    that case out."""
+    as_float = inputs == "float"
+    knobs = dict(pacer_window=8, alpha=(2.0, 1.5, 3.0)[seed])
+    legacy, new, via_views = (_LegacyOort(**knobs), OortSelector(**knobs),
+                              OortSelector(**knobs))
+    sels = (legacy, via_views) if as_float else (legacy, new, via_views)
+    setup = np.random.default_rng(200 + seed)
+    probs = setup.random(n)
+    durs = np.round(10 + 90 * setup.random(n))      # exact duration ties
+    fb = np.random.default_rng(300 + seed)
+    rngs = [np.random.default_rng(seed) for _ in range(3)]
+    n_target = max(4, n // 60)
+    for r in range(60):
+        ids, p, d, views = _census(setup, n, probs, durs)
+        if as_float:
+            for v in views:
+                v.est_duration = float(v.est_duration)
+        a = legacy.select(r, views, n_target, rngs[0])
+        c = via_views.select(r, views, n_target, rngs[2])
+        assert a == c, r
+        if not as_float:
+            assert a == new.select_arrays(r, ids, p, d, n_target, rngs[1]), r
+        # the round's window utility, to the bit and the type
+        w = legacy._util_history[-1]
+        for sel in sels[1:]:
+            assert sel._util_history[-1] == w, r
+            assert type(sel._util_history[-1]) is type(w), r
+        t_pref0 = legacy.t_pref if r == 0 else t_pref0
+        # identical post-round feedback (same utilities, same durations):
+        # even ids' utilities on a coarse grid, so the exploit order meets
+        # ties; odd ids' heavy-tailed, so slow learners are exploited too
         for lid in a:
-            u, d = float(fb.random()), float(10 + 50 * fb.random())
-            legacy.update_feedback(lid, stat_util=u, duration=d, round_idx=r)
-            new.update_feedback(lid, stat_util=u, duration=d, round_idx=r)
-    assert legacy.eps == new.eps
-    assert legacy.t_pref == new.t_pref
-    assert legacy._util_history == new._util_history
+            u = float(np.round(fb.random(), 1) if lid % 2 == 0
+                      else fb.lognormal(0.0, 2.0))
+            dur = np.float64(durs[lid] * (0.5 + fb.random()))
+            if as_float:
+                dur = float(dur)
+            for sel in sels:
+                sel.update_feedback(lid, stat_util=u, duration=dur,
+                                    round_idx=r)
+    assert legacy.t_pref > t_pref0                  # the pacer moved it
+    for sel in sels[1:]:
+        assert sel.eps == legacy.eps
+        assert sel.t_pref == legacy.t_pref
+        assert sel._util_history == legacy._util_history
+        assert [type(u) for u in sel._util_history] == [
+            type(u) for u in legacy._util_history]
+        assert sel._stat_util == legacy._stat_util
+        assert sel._duration == legacy._duration
+
+
+def test_oort_view_types_reach_the_pacer():
+    """Through ``select``, a view's own statistical utility and the numpy or
+    float type of its numbers enter the window utility as the oracle's
+    ``_utility`` gives them."""
+    legacy, via_views = _LegacyOort(), OortSelector()
+    views = [LearnerView(0, last_stat_util=0.1, est_duration=np.float64(90)),
+             LearnerView(1, last_stat_util=np.float64(0.2),
+                         est_duration=20.0),
+             LearnerView(2, last_stat_util=0.3, est_duration=80.0),
+             LearnerView(3, last_stat_util=0.7, est_duration=0.0),
+             LearnerView(4, last_stat_util=1e-17, est_duration=10.0)]
+    for sel in (legacy, via_views):
+        assert sel.select(0, views, 5, np.random.default_rng(0)) == [
+            4, 1, 2, 0, 3]
+        sel.update_feedback(2, stat_util=np.float64(0.4), duration=0.0)
+    for r in (1, 2):
+        a = legacy.select(r, views, 5, np.random.default_rng(r))
+        assert via_views.select(r, views, 5, np.random.default_rng(r)) == a
+    assert via_views._util_history == legacy._util_history
+    assert [type(u) for u in via_views._util_history] == [
+        type(u) for u in legacy._util_history]
+
+
+def test_view_selectors_restore_dict_checkpoints():
+    # selectors pickled before the array form hold their per-learner state
+    # as {learner_id: value} dicts; unpickling converts it
+    pri = PrioritySelector.__new__(PrioritySelector)
+    pri.__setstate__({"holdoff": 5, "_held_until": {3: 7, 0: 9}})
+    assert _held_dict(pri) == {0: 9, 3: 7}
+    oort = OortSelector.__new__(OortSelector)
+    state = dict(vars(OortSelector()), eps=0.0, t_pref=50.0,
+                 _util_history=[1.0],
+                 _stat_util={4: 0.5, 1: 2.0}, _duration={4: 30.0})
+    for k in ("_known", "_stat", "_stat_np", "_has_dur", "_dur", "_dur_np"):
+        del state[k]
+    oort.__setstate__(state)
+    assert oort._stat_util == {1: 2.0, 4: 0.5}
+    assert oort._duration == {4: 30.0}
+    assert oort.t_pref == 50.0 and oort._util_history == [1.0]
+    ids = np.arange(6)
+    assert oort.select_arrays(0, ids, np.zeros(6), np.full(6, 40.0), 2,
+                              np.random.default_rng(0)) == [1, 4]
+
+
+class _ViewsOnly(Selector):
+    """An out-of-tree view selector: implements ``select`` alone, so the
+    engine reaches it through the base ``select_arrays`` (LearnerViews)."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def select(self, round_idx, checked_in, n_target, rng):
+        return self.inner.select(round_idx, checked_in, n_target, rng)
+
+    def update_feedback(self, learner_id, **kw):
+        self.inner.update_feedback(learner_id, **kw)
+
+
+@pytest.mark.parametrize("selector", ["priority", "oort"])
+def test_view_adapter_engine_parity(selector):
+    """A Simulator whose view selector is reached through LearnerViews and
+    the ``select`` adapter takes the array path's decisions, round for round,
+    and leaves the RNG stream and the selector's state where the array path
+    does."""
+    cfg = SimConfig(n_learners=200, rounds=60, eval_every=30, n_target=6,
+                    selector=selector, mapping="label_uniform",
+                    dynamic_availability=True)
+
+    class Logged(Simulator):
+        def _begin_round(self, r):
+            plan = super()._begin_round(r)
+            self.log.append(None if plan is None else list(plan.chosen))
+            return plan
+
+    sims = []
+    for via_views in (False, True):
+        sim = Logged(cfg)
+        sim.log = []
+        if via_views:
+            sim.selector = _ViewsOnly(sim.selector)
+        sim.final = dict(sim.run().summary())
+        sims.append(sim)
+    a, b = sims
+    assert len(a.log) == 60 and sum(c is not None for c in a.log) > 30
+    assert a.log == b.log
+    assert a.rng.bit_generator.state == b.rng.bit_generator.state
+    assert a.final == b.final
+    assert pickle.dumps(a.selector) == pickle.dumps(b.selector.inner)
 
 
 # ---------------------------------------------------------------------------

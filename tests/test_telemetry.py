@@ -300,6 +300,24 @@ def test_session_exports_are_loadable(tmp_path):
     assert sess.registry.value("pipeline_rounds") > 0
 
 
+def test_checked_in_counter_sums_the_census():
+    # pipeline_checked_in: the census each round hands to selection, once a
+    # round; dynamic availability so the census changes round to round
+    census = []
+
+    class Census(Simulator):
+        def _available_now(self, t_now):
+            available = super()._available_now(t_now)
+            census.append(len(available))
+            return available
+
+    sess = TelemetrySession()
+    Census(_cfg(n_learners=60, rounds=12, dynamic_availability=True)).run(
+        telemetry=sess)
+    assert len(census) == 12 and len(set(census)) > 1
+    assert sess.registry.value("pipeline_checked_in") == sum(census) > 0
+
+
 def test_write_prometheus_roundtrip(tmp_path):
     reg = MetricsRegistry()
     reg.counter("x_total").inc(3)
