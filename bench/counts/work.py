@@ -1,7 +1,8 @@
 """Operations and bytes, counted from a configuration's shapes.
 
 These are the algorithm's counts, not the compiler's: they do not change
-when the implementation does.
+when the implementation does. A model's own counts are in its kind's file
+(``models/<kind>.py``); the SAA kernel's are here.
 
 - Parameters: every weight of the model as its equations define it.
 - Training FLOP: 6 per matmul weight per sample or token (forward 2,
@@ -16,45 +17,19 @@ when the implementation does.
 """
 from __future__ import annotations
 
-
-def mlp_params(m: dict) -> int:
-    d, h, c = int(m["dim"]), int(m["hidden"]), int(m["n_classes"])
-    return d * h + h + h * c + c
-
-
-def mlp_train_flop_per_sample(m: dict) -> float:
-    d, h, c = int(m["dim"]), int(m["hidden"]), int(m["n_classes"])
-    return 6.0 * (d * h + h * c)
-
-
-def _lm_dims(m: dict):
-    return (int(m["hidden_size"]), int(m["intermediate_size"]),
-            int(m["vocab_size"]), int(m["num_hidden_layers"]))
-
-
-def transformer_params(m: dict) -> int:
-    d, f, v, n_layers = _lm_dims(m)
-    per_layer = 4 * d * d + 3 * d * f + 2 * d      # attention, SwiGLU, norms
-    return 2 * v * d + d + n_layers * per_layer    # embedding, head, norm
-
-
-def transformer_train_flop_per_token(m: dict, seq_len: int) -> float:
-    d, f, v, n_layers = _lm_dims(m)
-    matmul = n_layers * (4 * d * d + 3 * d * f) + d * v
-    return 6.0 * matmul + 12.0 * n_layers * seq_len * d
+import kinds
 
 
 def params(m: dict) -> int:
-    return {"mlp": mlp_params,
-            "transformer": transformer_params}[m["kind"]](m)
+    """Parameters of a configuration's ``model`` block, counted by its
+    kind's file (``models/<kind>.py``)."""
+    return kinds.model(m["kind"]).params(m)
 
 
 def train_flop_per_sample(m: dict, seq_len: int = 0) -> float:
-    """FLOP of one trained sample: a feature row for the mlp, a whole
-    sequence of ``seq_len`` tokens for the transformer."""
-    if m["kind"] == "mlp":
-        return mlp_train_flop_per_sample(m)
-    return seq_len * transformer_train_flop_per_token(m, seq_len)
+    """FLOP of one trained sample (a feature row, or a whole sequence of
+    ``seq_len`` tokens), counted by the kind's file."""
+    return kinds.model(m["kind"]).train_flop_per_sample(m, seq_len)
 
 
 def saa_min_bytes(n: int, d: int) -> float:

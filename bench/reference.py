@@ -1,15 +1,10 @@
 """Plain reference of the federated round, written from the published
 equations, importing nothing of the program.
 
-The models:
-
-- ``mlp``: ``relu(x W1 + b1) W2 + b2``, mean softmax cross-entropy.
-- ``transformer``: pre-norm decoder layers (RMSNorm with a learned scale,
-  eps 1e-5), multi-head causal attention with rotary positions (base
-  10,000, rotate-half) and a 1/sqrt(head_dim) softmax scale, a SwiGLU
-  feed-forward (``(silu(h Wg) * h Wu) Wd``), a final RMSNorm and an untied
-  output head. The loss is the mean over sequences of the per-sequence mean
-  next-token cross-entropy.
+The model is the configuration's own: ``models/<kind>.py``, found by the
+``kind`` its ``model`` block names (``kinds.py`` says what such a file
+defines). A configuration adds its model as a file there. The training
+loss is the mean of the model's per-example losses.
 
 The round (REFL, arXiv:2111.01108, Alg. 2 and Eq. 2): every learner that
 reports trains ``local_steps`` plain SGD steps of ``local_lr`` from the
@@ -39,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import kinds
+
 EPS = 1e-12
 
 
@@ -48,111 +45,17 @@ def _key(seed: int):
     return jax.random.fold_in(k, (int(seed) >> 32) & 0xFFFFFFFF)
 
 
-# ---------------------------------------------------------------------------
-# Models
-# ---------------------------------------------------------------------------
-
-
-def _dense(key, shape):
-    return jax.random.normal(key, shape, jnp.float32) * shape[-2] ** -0.5
-
-
-def init_mlp(key, m: dict):
-    k1, k2 = jax.random.split(key)
-    dim, hid, c = int(m["dim"]), int(m["hidden"]), int(m["n_classes"])
-    return {"b1": jnp.zeros((hid,), jnp.float32),
-            "b2": jnp.zeros((c,), jnp.float32),
-            "w1": _dense(k1, (dim, hid)),
-            "w2": _dense(k2, (hid, c))}
-
-
-def init_transformer(key, m: dict):
-    d, f, v = int(m["hidden_size"]), int(m["intermediate_size"]), \
-        int(m["vocab_size"])
-    ks = jax.random.split(key, 9)
-    one = lambda a: a[None]         # the program stacks its one layer period
-    layer = {
-        "ffn": {"w_down": one(_dense(ks[0], (f, d))),
-                "w_gate": one(_dense(ks[1], (d, f))),
-                "w_up": one(_dense(ks[2], (d, f)))},
-        "mixer": {"w_k": one(_dense(ks[3], (d, d))),
-                  "w_o": one(_dense(ks[4], (d, d))),
-                  "w_q": one(_dense(ks[5], (d, d))),
-                  "w_v": one(_dense(ks[6], (d, d)))},
-        "norm1": {"scale": one(jnp.ones((d,), jnp.float32))},
-        "norm2": {"scale": one(jnp.ones((d,), jnp.float32))},
-    }
-    return {"embed": {"embedding": jax.random.normal(ks[7], (v, d)) * 0.02},
-            "final_norm": {"scale": jnp.ones((d,), jnp.float32)},
-            "head": {"w_out": _dense(ks[8], (d, v))},
-            "prefix": [],
-            "stack": {"sub0": layer}}
-
-
-def _mlp_loss(p, x, y, prec):
-    h = jax.nn.relu(jnp.matmul(x, p["w1"], precision=prec) + p["b1"])
-    logits = jnp.matmul(h, p["w2"], precision=prec) + p["b2"]
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-    return logits, logz - gold
-
-
-def _rms(x, scale):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * scale
-
-
-def _rope(x):
-    s, dh = x.shape[1], x.shape[-1]
-    inv = 1.0 / (10000.0 ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv      # (S, dh/2)
-    cos, sin = (jnp.cos(ang)[None, :, None, :].astype(x.dtype),
-                jnp.sin(ang)[None, :, None, :].astype(x.dtype))
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _lm_loss(p, tok, y, prec, n_heads):
-    mm = functools.partial(jnp.matmul, precision=prec)
-    blk = jax.tree.map(lambda a: a[0], p["stack"]["sub0"])
-    x = p["embed"]["embedding"][tok]
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _rms(x, blk["norm1"]["scale"])
-    q, k, v = (mm(h, blk["mixer"][w]).reshape(b, s, n_heads, dh)
-               for w in ("w_q", "w_k", "w_v"))
-    q, k = _rope(q), _rope(k)
-    att = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec) * dh ** -0.5
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", att, v, precision=prec)
-    x = x + mm(o.reshape(b, s, d), blk["mixer"]["w_o"])
-    h = _rms(x, blk["norm2"]["scale"])
-    ffn = blk["ffn"]
-    x = x + mm(jax.nn.silu(mm(h, ffn["w_gate"])) * mm(h, ffn["w_up"]),
-               ffn["w_down"])
-    logits = mm(_rms(x, p["final_norm"]["scale"]), p["head"]["w_out"])
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
-    return logits, (logz - gold).mean(-1)
-
-
 class Model:
-    """One configuration's reference model: init, per-example losses and
-    eval, in the arithmetic that ``dtype``/``precision`` select."""
+    """One configuration's reference model (``kinds.model``, found by the
+    ``kind`` of its ``model`` block): init, per-example losses and eval, in
+    the arithmetic that ``dtype``/``precision`` select."""
 
     def __init__(self, model: dict, dtype=jnp.float32, precision="highest"):
         self.spec = model
         self.dtype = dtype
-        if model["kind"] == "mlp":
-            self._init = init_mlp
-            self._loss = functools.partial(_mlp_loss, prec=precision)
-        elif model["kind"] == "transformer":
-            self._init = init_transformer
-            self._loss = functools.partial(
-                _lm_loss, prec=precision,
-                n_heads=int(model["num_attention_heads"]))
-        else:
-            raise ValueError(f"unknown model kind {model['kind']!r}")
+        kind = kinds.model(model["kind"])
+        self._init = kind.init
+        self._loss = functools.partial(kind.loss, prec=precision, m=model)
 
     def init(self, seed: int):
         """Initial weights from the seed, made on the device in one call."""
@@ -204,33 +107,38 @@ class Replay:
         def train(p0, idx):
             xs = self.x_tr[idx].reshape((steps, batch) + self.x_tr.shape[1:])
             ys = self.y_tr[idx].reshape((steps, batch) + self.y_tr.shape[1:])
-            p = p0
-            for t in range(steps):
+
+            def step(t, p):
                 g = jax.grad(model.mean_loss)(p, xs[t, :keep], ys[t, :keep])
-                p = jax.tree.map(lambda w, gw: w - jnp.asarray(lr, dt) * gw,
-                                 p, g)
+                return jax.tree.map(
+                    lambda w, gw: w - jnp.asarray(lr, dt) * gw, p, g)
+
+            # a loop, not unrolled: one step's memory at a time
+            p = jax.lax.fori_loop(0, steps, step, p0)
             return jax.tree.map(jnp.subtract, p, p0)
 
-        def weighted(acc, c, stack):
-            # acc + sum_j c_j stack_j, elementwise (no matmul unit)
-            return jax.tree.map(
-                lambda s, a: s + jnp.sum(
-                    c.astype(dt).reshape((-1,) + (1,) * (a.ndim - 1)) * a, 0),
-                acc, stack)
+        def lam(fresh_sum, us, nf):
+            # u_F is the fresh sum over n_F (0 in a round with none)
+            nf = nf.astype(dt)
+            uf = jax.tree.map(lambda s: s / jnp.maximum(nf, 1), fresh_sum)
+            return sqnorm(jax.tree.map(
+                lambda f, s: f - (s + nf * f) / (nf + 1), uf, us)) \
+                / (sqnorm(uf) + EPS)
 
-        # a round's rows, one after another in one call: the memory of
-        # one row's training, one dispatch per round
-        self._train = jax.jit(
-            lambda p0, idx: jax.lax.map(lambda i: train(p0, i), idx))
-        self._row = jax.jit(lambda stack, j: jax.tree.map(
-            lambda a: a[j], stack))
-        self._weighted = jax.jit(weighted)
+        def sum_rows(p0, idx, n):
+            # the first n rows of idx, trained one after another from p0
+            # and each added into the sum that the loop carries: the memory
+            # of one row's training beside the weights and the sum
+            return jax.lax.fori_loop(
+                0, n, lambda j, acc: jax.tree.map(jnp.add, acc,
+                                                  train(p0, idx[j])),
+                jax.tree.map(jnp.zeros_like, p0))
+
+        self._sum_rows = jax.jit(sum_rows)
         self._axpy = jax.jit(lambda acc, a, u: jax.tree.map(
             lambda s, x: s + a.astype(dt) * x, acc, u))
         self._zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
-        self._lam = jax.jit(lambda uf, us, nf: sqnorm(jax.tree.map(
-            lambda f, s: f - (s + nf.astype(dt) * f) / (nf.astype(dt) + 1),
-            uf, us)) / (sqnorm(uf) + EPS))
+        self._lam = jax.jit(lam)
         self._eval = jax.jit(model.evaluate)
 
     def weights(self, n_fresh: int, taus, lams) -> list:
@@ -248,38 +156,35 @@ class Replay:
         p = self.m.cast(params0)
         cache, losses = {}, {}
         width = max(len(e["bidx"]) for e in log)
+
+        def rows_sum(p, bidx, rows):
+            # the rows' samples padded to one width (a pad row repeats the
+            # first and is not trained): one program for every round
+            idx = np.stack([bidx[i] for i in rows]
+                           + [bidx[rows[0]]] * (width - len(rows)))
+            return self._sum_rows(p, jnp.asarray(idx), jnp.int32(len(rows)))
+
         for e in log:
             r = e["round"]
-            rows = sorted(set(e["fresh"]) | {i for i, _ in e["new_stale"]})
-            slot = {i: j for j, i in enumerate(rows)}
-            if rows:
-                # a round's rows train in one call, padded to one width (a
-                # pad row repeats the first and is dropped)
-                idx = np.stack([e["bidx"][i] for i in rows]
-                               + [e["bidx"][rows[0]]] * (width - len(rows)))
-                batch = self._train(p, jnp.asarray(idx))
-            for i, lid in e["new_stale"]:
-                cache[(lid, r)] = self._row(batch, jnp.int32(slot[i]))
             n_fresh = len(e["fresh"])
+            fresh_sum = rows_sum(p, e["bidx"], e["fresh"]) if n_fresh \
+                else self._zeros(p)
+            for i, lid in e["new_stale"]:
+                cache[(lid, r)] = rows_sum(p, e["bidx"], [i])
             stale = [cache.pop(key) for key in e["landing"]]
             taus = [r - origin for _lid, origin in e["landing"]]
             if n_fresh or stale:
-                # fresh rows by their slot in the round's stack
-                pick = np.zeros(width, np.float32)
-                pick[[slot[i] for i in e["fresh"]]] = 1.0
-                mean = self._zeros(p)
-                if n_fresh:
-                    mean = self._weighted(mean, jnp.asarray(pick / n_fresh),
-                                          batch)
-                lams = [float(self._lam(mean, u, jnp.int32(n_fresh)))
+                lams = [float(self._lam(fresh_sum, u, jnp.int32(n_fresh)))
                         for u in stale]
                 w = self.weights(n_fresh, taus, lams)
-                # the server step, global += server_lr * sum_i w_i u_i
+                # the server step, global += server_lr * sum_i w_i u_i, the
+                # fresh rows' equal weights applied to their sum
                 if n_fresh:
-                    p = self._weighted(
-                        p, jnp.asarray(pick * self.server_lr * w[0]), batch)
+                    p = self._axpy(p, jnp.float32(self.server_lr * w[0]),
+                                   fresh_sum)
                 for wi, u in zip(w[n_fresh:], stale):
                     p = self._axpy(p, jnp.float32(self.server_lr * wi), u)
+            del fresh_sum                     # a row's memory, freed for eval
             if r in eval_rounds:
                 losses[r] = float(self._eval(p, self.x_te, self.y_te)[1])
         return p, losses

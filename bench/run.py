@@ -6,14 +6,17 @@ run once.
 A cell names a configuration (``bench/configs/<config>.json``: the
 ``SimConfig`` fields, the model and the data of one deployment) and a
 traffic mix (``bench/traffic/<mix>.json``: the policy, the rounds of one
-simulation and the entry point). Per-layer metrics are readers in
+simulation and the entry point). The configuration's ``model`` block names
+its kind, and the reference model of that kind, with its counts, is
+``bench/models/<kind>.py`` (``kinds.py``). Per-layer metrics are readers in
 ``bench/metrics/<metric>.py``; the limits of the correctness check are in
-``bench/limits/<cell>.json``. Everything is found by name.
+``bench/limits/<cell>.json``. Everything is found by name, so a new model,
+mix, metric or cell enters as files.
 
 Set-up (``setup_s``, from process start): the world is built from the seed
 (the program's ``Substrate``, given the data of ``datagen.py`` and the
-initial weights of ``reference.py``, made on the device); the compile cache
-is placed; one warm-up simulation of the same configuration and seed
+initial weights of the reference model, made on the device); the compile
+cache is placed; one warm-up simulation of the same configuration and seed
 compiles every program the window will use.
 
 The window repeats that simulation, a whole ``Simulator.run()`` each time,
@@ -25,8 +28,9 @@ not counted) over the same seconds.
 
 After the window the last simulation's final parameters and eval losses
 are compared with the reference's replay of its round log
-(``reference.py``); the numbers compared and their limits are printed as
-the last lines of standard error and under ``checks`` in the result.
+(``reference.py``, one trained row at a time); the numbers compared and
+their limits are printed as the last lines of standard error and under
+``checks`` in the result.
 
 With ``--trace 1`` the window runs under the JAX profiler with the
 program's host spans on, and the result carries the cell's per-layer
@@ -49,7 +53,6 @@ import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
@@ -65,6 +68,7 @@ sys.path.insert(1, str(ROOT / "src"))
 
 import datagen  # noqa: E402
 import reference  # noqa: E402
+from kinds import module as _module  # noqa: E402
 import tracefile  # noqa: E402
 from counts import work  # noqa: E402
 
@@ -283,15 +287,6 @@ def check(world: World, prog_flat, prog_losses, log, limits: dict):
     checks = {k: {"value": numbers[k], "limit": limits[k]} for k in limits}
     ok = all(c["value"] <= c["limit"] for c in checks.values())
     return ok, checks
-
-
-def _module(path: pathlib.Path):
-    """A bench file loaded by path (metric names hold dots)."""
-    modspec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(modspec)
-    modspec.loader.exec_module(mod)
-    return mod
 
 
 def per_layer(spec_metrics, ctx) -> dict:

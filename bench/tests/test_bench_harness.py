@@ -17,31 +17,8 @@ sys.path.insert(0, str(BENCH))
 
 import run  # noqa: E402
 import tracefile  # noqa: E402
+from bench_cells import LM, MLP, run_quiet, small  # noqa: E402
 from counts import work  # noqa: E402
-
-MLP, LM = "mlp-speech-n1000.refl", "lm-minicpm2b-l1.silo8"
-
-
-def _cell(name: str) -> dict:
-    """A cell's files, found by name (``<config>.<traffic>``) even before
-    the cell has an entry in BENCHMARK.json."""
-    config, traffic = name.split(".")
-    return dict(config=run._json(BENCH / "configs" / f"{config}.json"),
-                traffic=run._json(BENCH / "traffic" / f"{traffic}.json"),
-                limits=run._json(BENCH / "limits" / f"{name}.json"))
-
-
-def _small(name: str) -> dict:
-    c = run.load_cell(name) if name == MLP else _cell(name)
-    if c["config"]["model"]["kind"] == "transformer":
-        c["config"]["model"].update(hidden_size=64, num_attention_heads=4,
-                                    intermediate_size=128)
-        c["config"]["sim"]["model_params"] = [
-            ["n_layers", 1], ["d_model", 64], ["n_heads", 4], ["d_ff", 128]]
-        c["traffic"]["sim"].update(rounds=4, eval_every=2)
-    else:
-        c["traffic"]["sim"].update(rounds=40, eval_every=20)
-    return c
 
 
 # --- counts ----------------------------------------------------------------
@@ -71,8 +48,8 @@ def test_param_count_matches_learner(config, expected):
 def test_lm_flop_per_token_is_six_n_plus_attention():
     m = run._json(BENCH / "configs" / "lm-minicpm2b-l1.json")["model"]
     n_matmul = 65_772_288 - 1024 * 2304 - 3 * 2304     # no embedding, norms
-    assert work.transformer_train_flop_per_token(m, 64) == \
-        6 * n_matmul + 12 * 64 * 2304
+    assert work.train_flop_per_sample(m, 64) == \
+        64 * (6 * n_matmul + 12 * 64 * 2304)
 
 
 # --- trace reduction -------------------------------------------------------
@@ -146,13 +123,8 @@ def test_param_change_gap_is_each_leafs_own():
     assert reference.leaf_norm_gap(p0, ref, ref) == 0.0
 
 
-def _run(c):
-    return run.run_cell(c, 2**31 + 11, 0.05, False, require_tpu=False,
-                        log=lambda *a, **k: None)
-
-
 def test_sound_run_is_correct(fresh_programs):
-    res = _run(_small(MLP))
+    res = run_quiet(small(MLP))
     assert res["correct"], res["checks"]
     assert list(res)[-1] == "checks"
     assert set(res["metrics"]) == {"rounds_per_s", "setup_s"}
@@ -161,7 +133,7 @@ def test_sound_run_is_correct(fresh_programs):
 @pytest.mark.parametrize("name", [MLP, LM])
 def test_lower_precision_control_fails(name):
     import jax.numpy as jnp
-    c = _small(name)
+    c = small(name)
     world = run.build_world(c["config"], c["traffic"], 2**31 + 5)
     sim, acct = run.simulate(world)
     losses, log = run.eval_losses(acct), sim.round_log
@@ -210,7 +182,7 @@ def _answer_altered(pipeline, monkeypatch):
 def test_planted_fault_is_not_correct(fault, fresh_programs, monkeypatch):
     from repro.sim import pipeline
     fault(pipeline, monkeypatch)
-    res = _run(_small(MLP))
+    res = run_quiet(small(MLP))
     assert not res["correct"], res["checks"]
 
 
