@@ -17,8 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.staleness import (RULE_ID, staleness_weights,
-                                  staleness_weights_by_id)
+from repro.core.staleness import (RULE_ID, counted_staleness_weights_by_id,
+                                  staleness_weights, staleness_weights_by_id)
 
 
 # ---------------------------------------------------------------------------
@@ -104,6 +104,16 @@ def _waa_by_id(stacked, fresh, tau, valid, beta, rule_id):
 # vmaps inside its fused round program (repro.sim.pipeline); same code the
 # batched sweep program below runs, so both paths share one set of numerics
 weights_and_aggregate_by_id = _waa_by_id
+
+
+def counted_weights_and_aggregate_by_id(stacked, fresh_n, tau, valid, beta,
+                                        rule_id):
+    """``weights_and_aggregate_by_id`` over rows that each stand for
+    ``fresh_n`` fresh updates (``counted_staleness_weights_by_id``): the
+    streamed round's fresh mean and its landing stale rows."""
+    w = counted_staleness_weights_by_id(stacked, fresh_n, tau, rule_id,
+                                        beta=beta, valid=valid)
+    return aggregate_updates(stacked, w), w
 
 
 @jax.jit

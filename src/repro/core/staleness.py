@@ -14,7 +14,9 @@ Fresh updates always get w_f = 1; the final coefficients are w_i / sum_j w_j.
 
 All functions are jittable over *stacked flat* updates ``U (n, D)`` with a
 boolean ``fresh`` mask — this is the oracle for the fused Pallas kernel in
-``repro.kernels.staleness_agg``.
+``repro.kernels.staleness_agg``.  ``counted_staleness_weights_by_id`` takes
+a fresh *count* per row instead, so that one row can stand for the mean of
+``n_F`` fresh updates (the streamed round's operand).
 """
 from __future__ import annotations
 
@@ -109,5 +111,31 @@ def staleness_weights_by_id(updates, fresh, tau, rule_id, *, beta=0.35,
     w_stale = jax.lax.switch(rule_id, [SCALING_RULES[r] for r in RULE_ORDER],
                              tau, lam, lam_max, beta)
     w = jnp.where(fresh, 1.0, w_stale)
+    w = jnp.where(valid, w, 0.0)
+    return w / jnp.maximum(w.sum(), EPS)
+
+
+def counted_staleness_weights_by_id(updates, fresh_n, tau, rule_id, *,
+                                    beta=0.35, valid=None):
+    """Eq. 2's normalized coefficients when a row may stand for several
+    fresh updates: ``fresh_n`` (n,) float counts the fresh updates a row
+    is the mean of (0 for a stale row).  With counts of 0 and 1 this is
+    ``staleness_weights_by_id``; with the fresh mean of ``n_F`` updates in
+    one row of count ``n_F`` it gives that row the weight ``n_F`` (the sum
+    of its rows' weights) and every stale row its exact Eq. 2 weight, since
+    ``Lam_s`` needs only ``u_hat_F`` and ``n_F``."""
+    if valid is None:
+        valid = jnp.ones(fresh_n.shape, bool)
+    fresh = fresh_n > 0
+    n_f = fresh_n.sum()
+    u_hat = (fresh_n[:, None] * updates).sum(axis=0) / jnp.maximum(n_f, 1.0)
+    mixed = (updates + n_f * u_hat[None, :]) / (n_f + 1.0)
+    num = jnp.sum((u_hat[None, :] - mixed) ** 2, axis=-1)
+    lam = jnp.where(fresh, 0.0, num / (jnp.sum(u_hat ** 2) + EPS))
+    stale_mask = (~fresh) & valid
+    lam_max = jnp.max(jnp.where(stale_mask, lam, 0.0))
+    w_stale = jax.lax.switch(rule_id, [SCALING_RULES[r] for r in RULE_ORDER],
+                             tau, lam, lam_max, beta)
+    w = jnp.where(fresh, fresh_n, w_stale)
     w = jnp.where(valid, w, 0.0)
     return w / jnp.maximum(w.sum(), EPS)

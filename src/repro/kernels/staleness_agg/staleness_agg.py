@@ -41,7 +41,8 @@ D_BLK = 2048  # lane-aligned (16 x 128); (n<=64) x 2048 fp32 = 512 KB per operan
 def _deviation_increments(u, fresh):
     """Eq. 2 deviation partials for one (n, D_BLK) tile.
 
-    u: (n, D_BLK) fp32; fresh: (n, 1) fp32 {0,1}.  Returns the tile's
+    u: (n, D_BLK) fp32; fresh: (n, 1) fp32 fresh counts (1 a fresh row, n_F
+    a fresh mean, 0 a stale row).  Returns the tile's
     contribution (num (n, 1), den (1, 1)) — the single implementation of the
     partials math shared by every kernel variant.
     """
@@ -94,12 +95,16 @@ def _compute_weights(rule, fresh, tau, beta, num, den, valid):
 
     ``valid`` masks bucket-padding rows (zero weight, excluded from the
     stale max), mirroring ``core.staleness.staleness_weights``'s mask.
+    A fresh row weighs ``fresh``: 1 for one update, ``n_F`` for a row that
+    is the mean of ``n_F`` fresh updates (the deviation partials then take
+    ``n_F`` as the fresh count too), as in
+    ``core.staleness.counted_staleness_weights_by_id``.
     """
     lam = jnp.where(fresh > 0, 0.0, num / (den + EPS))
     stale = (fresh <= 0) & (valid > 0)
     lam_max = jnp.max(jnp.where(stale, lam, 0.0))
     w_stale = SCALING_RULES[rule](tau, lam, lam_max, beta)
-    w = jnp.where(fresh > 0, 1.0, w_stale)
+    w = jnp.where(fresh > 0, fresh, w_stale)
     w = jnp.where(valid > 0, w, 0.0)
     return w / jnp.maximum(w.sum(), EPS)
 
@@ -261,7 +266,9 @@ def sweep_fused_staleness_apply(params, updates, fresh, tau, valid, scal, *,
     """Batched fused server step: new_params[s] = params[s] + lr_s * (w_s @ U_s).
 
     params: (S, D) fp32, D % D_BLK == 0, aliased input->output; updates:
-    (S, n, D) fp32; fresh/valid: (S, n) bool; tau: (S, n) int; scal: (S, 2)
+    (S, n, D) fp32; fresh/valid: (S, n) bool (``fresh`` may instead count
+    the fresh updates a row is the mean of, ``_compute_weights``); tau:
+    (S, n) int; scal: (S, 2)
     fp32 rows ``(beta_s, server_lr_s)``.  One kernel launch computes every
     cell's deviation partials, in-kernel Eq. 2 weights and aggregate, and
     applies the aggregate to the cell's parameter row in place.  Returns

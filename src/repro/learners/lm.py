@@ -14,6 +14,10 @@ consumes.  Federated specifics:
   on token workloads.
 - ``evaluate`` reports (next-token accuracy, mean NLL) — the eval lane
   treats these exactly like the classifier's (accuracy, loss) pair.
+- ``vocab`` (every LM) sets the vocabulary; 0 takes the dataset's.  The
+  ``transformer`` learner also takes a tied head and MiniCPM's muP
+  multipliers (``scale_emb``, ``scale_depth`` over ``sqrt(depth_layers)``,
+  ``dim_model_base``); 0 leaves each out of the program.
 
 These models train on ``data_kind="tokens"`` benchmarks (``tokens`` /
 ``tokens_skew``: ``repro.data.synthetic.federated_token_shards`` wired
@@ -62,6 +66,7 @@ _BASE_KNOBS = (
     Knob("d_model", 64, "model width"),
     Knob("n_heads", 2, "attention / wkv heads"),
     Knob("d_ff", 128, "dense SwiGLU width"),
+    Knob("vocab", 0, "vocabulary size (0 = the dataset's)"),
 )
 
 
@@ -72,9 +77,22 @@ def _base_cfg(knobs: dict, meta, **over) -> tf.ModelConfig:
         n_heads=int(knobs["n_heads"]),
         n_kv_heads=int(knobs["n_heads"]),
         d_ff=int(knobs["d_ff"]),
-        vocab_size=int(meta.vocab),
+        vocab_size=int(knobs["vocab"]) or int(meta.vocab),
         param_dtype=jnp.float32,
         **over)
+
+
+def _mup(knobs: dict) -> dict:
+    """MiniCPM's muP multipliers and the tied head, from the knobs; a knob
+    at 0 leaves its multiplier out of the program."""
+    def opt(name, cast):
+        v = cast(knobs[name])
+        return v if v else None
+    return dict(tie_embeddings=bool(int(knobs["tie_embeddings"])),
+                scale_emb=opt("scale_emb", float),
+                scale_depth=opt("scale_depth", float),
+                scale_depth_layers=opt("depth_layers", int),
+                dim_model_base=opt("dim_model_base", int))
 
 
 def _check_kernels_trainable(knobs: dict, why: str) -> None:
@@ -93,7 +111,7 @@ def _build_transformer(knobs: dict, meta) -> ModelFns:
     return _fns_for(_base_cfg(
         knobs, meta, arch_id="fl-transformer",
         window=window if window > 0 else None,
-        use_kernels=bool(int(knobs["use_kernels"]))))
+        use_kernels=bool(int(knobs["use_kernels"])), **_mup(knobs)))
 
 
 def _build_moe(knobs: dict, meta) -> ModelFns:
@@ -122,6 +140,14 @@ register_model(ModelSpec(
     knobs=_BASE_KNOBS + (
         Knob("window", 0, "sliding-window width (0 = full causal)"),
         Knob("use_kernels", 0, "route attention through the Pallas kernel"),
+        Knob("tie_embeddings", 0, "1 = the output head is the embedding"),
+        Knob("scale_emb", 0.0, "muP embedding multiplier (0 = none)"),
+        Knob("scale_depth", 0.0, "muP residual-branch scale over "
+                                 "sqrt(depth_layers) (0 = none)"),
+        Knob("depth_layers", 0, "depth scale_depth is divided by "
+                                "(0 = n_layers)"),
+        Knob("dim_model_base", 0, "muP logit divisor's base width: logits "
+                                  "of h / (d_model / base) (0 = none)"),
     ),
 ))
 

@@ -83,6 +83,11 @@ class ModelConfig:
     mla_absorb: bool = False         # absorbed-matmul MLA decode (beyond-paper)
     loss_chunk: int = 0              # >0: chunk the LM loss over sequence
     remat: bool = False              # activation checkpointing on super-blocks
+    # --- muP (MiniCPM, arXiv:2404.06395); None leaves the path untouched ---
+    scale_emb: Optional[float] = None     # x0 = scale_emb * E[tok]
+    scale_depth: Optional[float] = None   # x += scale_depth/sqrt(L) * branch
+    scale_depth_layers: Optional[int] = None  # that L (None: n_layers)
+    dim_model_base: Optional[int] = None  # logits of h / (d_model / base)
 
     @property
     def head_dim(self) -> int:
@@ -94,6 +99,16 @@ class ModelConfig:
             return self.vocab_size
         m = self.vocab_pad_to
         return (self.vocab_size + m - 1) // m * m
+
+    @property
+    def residual_scale(self) -> Optional[float]:
+        """Scale of each residual branch, ``scale_depth / sqrt(L)``; ``L``
+        is the published depth where the model is cut in depth (the layers
+        left out lie on other pipeline stages)."""
+        if self.scale_depth is None:
+            return None
+        layers = self.scale_depth_layers or self.n_layers
+        return self.scale_depth / layers ** 0.5
 
     def mixer_of(self, layer_idx: int) -> str:
         return self.block_pattern[layer_idx % len(self.block_pattern)]
@@ -227,22 +242,28 @@ def _ffn_forward(cfg, spec, p, x):
                                top_k=cfg.top_k, group_size=cfg.moe_group_size)
 
 
+def _branch(cfg, h):
+    """A residual branch's output, muP-scaled where the model says so."""
+    scale = cfg.residual_scale
+    return h if scale is None else h * jnp.asarray(scale, h.dtype)
+
+
 def _layer_forward(cfg, spec, p, x, positions, state):
     h, new_state = _mixer_forward(cfg, spec, p["mixer"],
                                   L.rmsnorm(p["norm1"], x, cfg.norm_eps),
                                   positions, state)
-    x = x + h
+    x = x + _branch(cfg, h)
     h, aux = _ffn_forward(cfg, spec, p, L.rmsnorm(p["norm2"], x, cfg.norm_eps))
-    return x + h, new_state, aux
+    return x + _branch(cfg, h), new_state, aux
 
 
 def _layer_decode(cfg, spec, p, x, position, state):
     h, new_state = _mixer_decode(cfg, spec, p["mixer"],
                                  L.rmsnorm(p["norm1"], x, cfg.norm_eps),
                                  position, state)
-    x = x + h
+    x = x + _branch(cfg, h)
     h, aux = _ffn_forward(cfg, spec, p, L.rmsnorm(p["norm2"], x, cfg.norm_eps))
-    return x + h, new_state, aux
+    return x + _branch(cfg, h), new_state, aux
 
 
 # ---------------------------------------------------------------------------
@@ -281,9 +302,16 @@ def init_params(cfg: ModelConfig, key):
 # ---------------------------------------------------------------------------
 
 
+def _embed_tokens(cfg, params, tokens):
+    x = L.embed_lookup(params["embed"], tokens)
+    if cfg.scale_emb is not None:
+        x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+    return x
+
+
 def _embed_inputs(cfg, params, batch):
     """batch: {"tokens": (B, S_text)[, "frontend_embeds": (B, P, d_frontend)]}"""
-    x = L.embed_lookup(params["embed"], batch["tokens"])
+    x = _embed_tokens(cfg, params, batch["tokens"])
     if cfg.frontend == "vision":
         fe = fr.project_frontend(params["frontend"], batch["frontend_embeds"])
         x = jnp.concatenate([fe.astype(x.dtype), x], axis=1)
@@ -291,6 +319,10 @@ def _embed_inputs(cfg, params, batch):
 
 
 def _logits(cfg, params, x):
+    if cfg.dim_model_base is not None:
+        # muP's output multiplier: the final hidden state over the width
+        # ratio to the base model, before the head
+        x = x / jnp.asarray(cfg.d_model / cfg.dim_model_base, x.dtype)
     if cfg.tie_embeddings:
         logits = x @ params["embed"]["embedding"].T.astype(x.dtype)
     else:
@@ -422,7 +454,7 @@ def decode_step(cfg: ModelConfig, params, state, tokens, position):
     Returns (logits (B, vocab), new_state).
     """
     prefix, specs, n_rep = cfg.segment_plan()
-    x = L.embed_lookup(params["embed"], tokens[:, None])
+    x = _embed_tokens(cfg, params, tokens[:, None])
 
     new_prefix = []
     for p, spec, st in zip(params["prefix"], prefix, state["prefix"]):

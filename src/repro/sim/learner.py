@@ -50,6 +50,13 @@ def local_train(params, xs, ys, lr: float, prox_mu: float = 0.0, *,
     own program and the default keeps the pre-model-zoo cache key.
     Returns (delta pytree, mean loss, sqrt(mean loss^2) for Oort stat-util).
     """
+    final, loss_m, l2 = _local_sgd(params, xs, ys, lr, prox_mu, loss)
+    delta = jax.tree.map(lambda a, b: a - b, final, params)
+    return delta, loss_m, l2
+
+
+def _local_sgd(params, xs, ys, lr, prox_mu, loss):
+    """The K steps themselves: (trained params, mean loss, Oort l2 stat)."""
     p0 = params
     loss_fn = loss
 
@@ -62,8 +69,7 @@ def local_train(params, xs, ys, lr: float, prox_mu: float = 0.0, *,
         return p, (loss, jnp.sqrt(jnp.mean(losses ** 2)))
 
     final, (losses, l2s) = jax.lax.scan(step, params, (xs, ys))
-    delta = jax.tree.map(lambda a, b: a - b, final, params)
-    return delta, losses.mean(), l2s.mean()
+    return final, losses.mean(), l2s.mean()
 
 
 # vmap over the participant axis — one compiled program trains the whole cohort
@@ -98,6 +104,20 @@ def local_train_flat(flat_params, xs, ys, *, spec, lr, prox_mu,
         flat = jnp.concatenate(
             [flat, jnp.zeros((int(out_dim) - flat.shape[0],), jnp.float32)])
     return flat, loss_v, l2
+
+
+def local_train_leaves(flat_params, xs, ys, *, spec, lr, prox_mu,
+                       loss=_xent):
+    """One learner's local round from a flat parameter row, with its
+    parameters before and after as leaves in ``spec`` order rather than a
+    flat delta: the streamed round program writes ``after - before`` leaf
+    by leaf into the fresh sum or a cache slot, so no delta row is built.
+    Returns (leaves before, leaves after, mean loss, Oort l2 stat); the
+    steps are ``local_train``'s."""
+    from repro.core.aggregation import unflatten_update
+    start = unflatten_update(flat_params, spec)
+    final, loss_v, l2 = _local_sgd(start, xs, ys, lr, prox_mu, loss)
+    return jax.tree.leaves(start), jax.tree.leaves(final), loss_v, l2
 
 
 @jax.jit

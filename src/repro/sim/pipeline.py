@@ -106,7 +106,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation as agg
-from repro.core.aggregation import (aggregate_updates, unflatten_update,
+from repro.core.aggregation import (aggregate_updates,
+                                    counted_weights_and_aggregate_by_id,
+                                    unflatten_update,
                                     weights_and_aggregate_by_id,
                                     yogi_apply_flat)
 from repro.core.stale_cache import DeviceStaleCache, ShardedSlotAccounts
@@ -127,6 +129,30 @@ from repro.telemetry.schema import (DISPATCH_KINDS, GUARD_COUNTERS,
 
 ROW_BLOCK = 128   # packed participant-row padding bucket (bucket_block)
 UPD_BLOCK = 32    # per-cell aggregation-row padding bucket (sweep_bucket_pad's)
+
+# float32 rows of width D that the vmapped round holds: per packed row its
+# parameter copy, gradient, updated parameters and delta; besides them the
+# parameters with their scratch row, the stale cache and the operand
+VMAP_ROW_COPIES = 4
+VMAP_FIXED_COPIES = 6
+
+
+@functools.lru_cache(maxsize=1)
+def device_bytes_limit() -> Optional[int]:
+    """The first local device's memory, where its backend reports it (read
+    once: it does not change while the process lives)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def stream_cohort(d: int, rows: int, bytes_limit: Optional[int]) -> bool:
+    """Whether a round trains its ``rows`` packed rows of width ``d`` one
+    at a time (``_stream_rows``) instead of under ``vmap``: where the
+    vmapped cohort would not fit the device's ``bytes_limit``.  A backend
+    that reports no limit keeps ``vmap``."""
+    if not bytes_limit:
+        return False
+    return 4 * d * (VMAP_ROW_COPIES * rows + VMAP_FIXED_COPIES) > bytes_limit
 
 
 def pipeline_key(cfg) -> tuple:
@@ -203,6 +229,10 @@ class PipelineStats:
     pad_rows = property(
         lambda s: s._counter("pad_rows").value,
         lambda s, v: setattr(s._counter("pad_rows"), "value", v))
+    # rows trained one at a time by a streamed round program
+    streamed_rows = property(
+        lambda s: s._counter("streamed_rows").value,
+        lambda s, v: setattr(s._counter("streamed_rows"), "value", v))
     # the census each round hands to selection (its per-round work)
     checked_in = property(
         lambda s: s._counter("checked_in").value,
@@ -227,6 +257,7 @@ class PipelineStats:
             "feedback_fetches": self.feedback_fetches,
             "trained_rows": self.trained_rows,
             "pad_rows": self.pad_rows,
+            "streamed_rows": self.streamed_rows,
             "checked_in": self.checked_in,
             "guard": dict(self.guard),
         }
@@ -241,7 +272,7 @@ class PipelineStats:
 def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
                 *, train_unit, steps, batch, yogi, use_kernel, kernel_rule,
                 single, p_axis=None, guard=None, faulty=False, lane=False,
-                attack=None, robust=None, norm_d=None):
+                attack=None, robust=None, norm_d=None, stream_unit=None):
     """One round's device work on one (local) params/cache block.
 
     params: (rows, D) — cell rows plus one scratch row; cache: (C + 1, D)
@@ -296,12 +327,24 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     Computed after the psum → no extra collective; lane off returns a
     zero-width block, so the program's outputs and numerics are untouched.
 
+    ``streamed`` (static, the last entry of ``shapes``; ``stream_cohort``)
+    trains the round's real rows one at a time with ``stream_unit``
+    (``learner.local_train_leaves``; ``_stream_rows``): a fresh row is
+    added into its group's fresh sum, a straggler row is written to its
+    cache slot, and the aggregation operand is the fresh mean and the
+    landing stale rows, ``(G, 1 + ns, D)``, weighed by Eq. 2 with the mean
+    counting ``n_F`` fresh updates — so no ``(R, D)`` delta stack exists.
+    The row count rides as the last int of ``ints``.  Guarded, robust,
+    attacked, lane-emitting and participant-sharded programs need the full
+    operand: ``RoundPipeline`` refuses to stream them.
+
     Named scopes ``train`` / ``cache`` (scatter and operand gather) /
+    ``fresh_sum`` (the streamed round's sum and mean of its fresh rows) /
     ``aggregate`` (screens, SAA weights and aggregate; the fused kernel
     also applies) / ``apply`` (server step) name the device ops in a
     profile; they add metadata only, no op.
     """
-    r_b, tb, g_b, nf_b, ns_b, all_valid = shapes
+    r_b, tb, g_b, nf_b, ns_b, all_valid, streamed = shapes
     n_b = nf_b + ns_b
     o = [0]
 
@@ -328,6 +371,7 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     agg_att = (take(g_b * n_b, (g_b, n_b), bool) if attack is not None
                else None)
     beta_g, lr_g = floats[:g_b], floats[g_b:2 * g_b]
+    fscale = floats[2 * g_b:2 * g_b + r_b] if faulty else None
 
     # --- train: gather batches + per-row params, one vmapped call ---
     with jax.named_scope("train"):
@@ -338,6 +382,17 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
         bx = bx.reshape((r_b, steps, batch) + bx.shape[2:])
         by = y_tr[row_sub[:, None], batch_idx]
         by = by.reshape((r_b, steps, batch) + by.shape[2:])
+    if streamed:
+        n_rows = take(1)[0]
+        cache, fsum, losses, l2s = _stream_rows(
+            stream_unit, params, cache, bx, by, row_cell, scat_slot, fr_idx,
+            agg_valid[:, :nf_b], fscale, n_rows, single=single)
+        return _streamed_aggregate(
+            params, cache, opt_state, fsum, losses, l2s, sl_idx, agg_cell,
+            agg_tau, agg_fresh, agg_valid, has_g, beta_g, lr_g, rule_id,
+            nf_b=nf_b, yogi=yogi, use_kernel=use_kernel,
+            kernel_rule=kernel_rule)
+    with jax.named_scope("train"):
         if single:
             deltas, losses, l2s = jax.vmap(
                 train_unit, in_axes=(None, 0, 0))(params[0], bx, by)
@@ -350,7 +405,6 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
         if faulty:
             # injected corruption: one IEEE fp32 multiply per delta row —
             # before the scatter, so cached straggler rows carry the fault too
-            fscale = floats[2 * g_b:2 * g_b + r_b]
             deltas = deltas * fscale[:, None]
         # scatter FIRST so the donated cache updates in place (a gather
         # before the scatter would force XLA to copy the whole buffer);
@@ -548,6 +602,126 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
     return params, cache, opt_state, losses, l2s, gstats, lanes
 
 
+def _stream_rows(stream_unit, params, cache, bx, by, row_cell, scat_slot,
+                 fr_idx, fr_valid, fscale, n_rows, *, single):
+    """The streamed round's training: its first ``n_rows`` packed rows (the
+    real ones; the bucket's pad rows are not trained), one after another.
+    Each fresh row's delta is added into its aggregation group's fresh
+    sum, kept as the parameter leaves (so the add needs no relayout), and
+    each straggler row's delta is flattened into its cache slot; a row
+    that is neither (a late row without SAA) only reports its loss.
+    Returns (cache, fresh-sum leaves (G, *leaf), losses (R,), l2s (R,))."""
+    r_b = bx.shape[0]
+    g_b, d = fr_idx.shape[0], cache.shape[-1]
+    trash = cache.shape[0] - 1
+    # each packed row's aggregation group if it is fresh, else -1 (padding
+    # columns point at row 0 with -1, and max keeps the real entries)
+    row_g = jnp.full((r_b,), -1, jnp.int32).at[fr_idx].max(
+        jnp.where(fr_valid, jnp.arange(g_b, dtype=jnp.int32)[:, None], -1))
+
+    def deltas(before, after, j):
+        out = [a - b for b, a in zip(before, after)]
+        return out if fscale is None else [u * fscale[j] for u in out]
+
+    def add(fsum, g, before, after, j):
+        return [jax.lax.dynamic_update_index_in_dim(
+            f, jax.lax.dynamic_index_in_dim(f, g, keepdims=False) + u, g, 0)
+            for f, u in zip(fsum, deltas(before, after, j))]
+
+    def put(cache, slot, before, after, j):
+        flat = [u.ravel() for u in deltas(before, after, j)]
+        pad = d - sum(u.size for u in flat)
+        row = jnp.concatenate(flat + [jnp.zeros((pad,), jnp.float32)])
+        return jax.lax.dynamic_update_index_in_dim(cache, row, slot, 0)
+
+    def row(j, carry):
+        cache, fsum, losses, l2s = carry
+        p = params[0] if single else params[row_cell[j]]
+        with jax.named_scope("train"):
+            before, after, loss, l2 = stream_unit(p, bx[j], by[j])
+        g, slot = row_g[j], scat_slot[j]
+        with jax.named_scope("fresh_sum"):
+            fsum = jax.lax.cond(g >= 0,
+                                lambda f: add(f, g, before, after, j),
+                                lambda f: f, fsum)
+        with jax.named_scope("cache"):
+            cache = jax.lax.cond(slot != trash,
+                                 lambda c: put(c, slot, before, after, j),
+                                 lambda c: c, cache)
+        return (cache, fsum, losses.at[j].set(loss), l2s.at[j].set(l2))
+
+    p_shape = jax.eval_shape(stream_unit, params[0], bx[0], by[0])[0]
+    with jax.named_scope("fresh_sum"):
+        fsum = [jnp.zeros((g_b,) + b.shape, jnp.float32) for b in p_shape]
+    zeros = jnp.zeros((r_b,), jnp.float32)
+    return jax.lax.fori_loop(0, n_rows, row, (cache, fsum, zeros, zeros))
+
+
+def _streamed_aggregate(params, cache, opt_state, fsum, losses, l2s, sl_idx,
+                        agg_cell, agg_tau, agg_fresh, agg_valid, has_g,
+                        beta_g, lr_g, rule_id, *, nf_b, yogi, use_kernel,
+                        kernel_rule):
+    """Eq. 2 over the streamed round's operand, ``(G, 1 + ns, D)``: each
+    group's fresh mean (its fresh-sum leaves flattened, over ``n_F``),
+    counting ``n_F`` fresh updates, and its landing stale rows, through the
+    SAA kernel or the jnp path; then the server step.  Returns the round
+    body's outputs."""
+    g_b, ns_b = sl_idx.shape
+    n_f = agg_fresh[:, :nf_b].sum(axis=-1).astype(jnp.float32)     # (G,)
+    d = cache.shape[-1]
+    with jax.named_scope("fresh_sum"):
+        # the fresh mean as a flat row of the cache's width (zero tail)
+        flat = [f.reshape(g_b, -1) for f in fsum]
+        pad = d - sum(f.shape[1] for f in flat)
+        mean = jnp.concatenate(flat + [jnp.zeros((g_b, pad), jnp.float32)],
+                               axis=1) / jnp.maximum(n_f, 1.0)[:, None]
+    with jax.named_scope("cache"):
+        if ns_b:
+            us = jnp.where(agg_valid[:, nf_b:, None], cache[sl_idx], 0.0)
+            u = jnp.concatenate([mean[:, None], us], axis=1)
+        else:
+            u = mean[:, None]
+    fresh_n = jnp.concatenate([n_f[:, None],
+                               jnp.zeros((g_b, ns_b), jnp.float32)], axis=1)
+    valid = jnp.concatenate([(n_f > 0)[:, None], agg_valid[:, nf_b:]], axis=1)
+    tau = jnp.concatenate([jnp.zeros((g_b, 1), agg_tau.dtype),
+                           agg_tau[:, nf_b:]], axis=1)
+    rows_old = params[agg_cell]                                   # (G, D)
+    with jax.named_scope("aggregate"):
+        if use_kernel:
+            from repro.kernels.staleness_agg.staleness_agg import (
+                sweep_fused_staleness_apply, sweep_fused_staleness_aggregate)
+            if yogi:
+                agg_out, _ = sweep_fused_staleness_aggregate(
+                    u, fresh_n, tau, beta_g, valid, rule=kernel_rule)
+            else:
+                new_rows, _ = sweep_fused_staleness_apply(
+                    rows_old, u, fresh_n, tau, valid,
+                    jnp.stack([beta_g, lr_g], axis=1), rule=kernel_rule)
+        elif ns_b == 0:
+            # the fresh mean alone: its normalized weight is exactly 1
+            agg_out = mean
+        else:
+            agg_out, _ = jax.vmap(counted_weights_and_aggregate_by_id)(
+                u, fresh_n, tau, valid, beta_g, rule_id)
+    with jax.named_scope("apply"):
+        if yogi:
+            state_rows = jax.tree.map(lambda s: s[agg_cell], opt_state)
+            new_rows, new_state = jax.vmap(yogi_apply_flat)(
+                rows_old, agg_out, state_rows)
+            opt_state = jax.tree.map(
+                lambda s, ns, os: s.at[agg_cell].set(
+                    jnp.where(has_g.reshape((-1,) + (1,) * (ns.ndim - 1)),
+                              ns, os)),
+                opt_state, new_state, state_rows)
+        elif not use_kernel:
+            new_rows = rows_old + lr_g[:, None] * agg_out
+        new_rows = jnp.where(has_g[:, None], new_rows, rows_old)
+        params = params.at[agg_cell].set(new_rows)
+    return (params, cache, opt_state, losses, l2s,
+            jnp.zeros((g_b, 6), jnp.int32), jnp.zeros((g_b, 0), jnp.float32))
+
+
 @functools.lru_cache(maxsize=16)
 def _chunk_program(spec, lr, prox_mu, steps, batch, yogi, use_kernel,
                    kernel_rule, guard, faulty, lane, attack, robust,
@@ -574,11 +748,14 @@ def _chunk_program(spec, lr, prox_mu, steps, batch, yogi, use_kernel,
     train_unit = functools.partial(ln.local_train_flat, spec=spec, lr=lr,
                                    prox_mu=prox_mu, loss=loss,
                                    out_dim=out_dim)
+    stream_unit = functools.partial(ln.local_train_leaves, spec=spec, lr=lr,
+                                    prox_mu=prox_mu, loss=loss)
     body = functools.partial(_round_body, train_unit=train_unit, steps=steps,
                              batch=batch, yogi=yogi, use_kernel=use_kernel,
                              kernel_rule=kernel_rule, guard=guard,
                              faulty=faulty, lane=lane, attack=attack,
-                             robust=robust, single=single, norm_d=norm_d)
+                             robust=robust, single=single, norm_d=norm_d,
+                             stream_unit=stream_unit)
 
     def prog(params, cache, opt_state, x_tr, y_tr, ints_k, floats_k, shapes):
         def step(carry, xs):
@@ -780,12 +957,15 @@ class RoundPipeline:
             self.d_pad = self.d + ((-self.d) % D_BLK)
         else:
             self.d_pad = self.d
-        pad_w = self.d_pad - self.d
+        d, pad_w = self.d, self.d_pad - self.d
 
         def _pad_rows(a):
             # widen the trailing D axis with zero columns (jnp/np alike);
-            # identity on the unpadded layout and on non-D leaves (yogi "t")
-            if pad_w and np.ndim(a) and np.shape(a)[-1] == self.d:
+            # identity on the unpadded layout and on non-D leaves (yogi "t").
+            # It closes over locals, not ``self``: a pipeline that held a
+            # closure over itself would live until the next garbage
+            # collection, with its parameter and cache buffers
+            if pad_w and np.ndim(a) and np.shape(a)[-1] == d:
                 width = [(0, 0)] * (np.ndim(a) - 1) + [(0, pad_w)]
                 return (np.pad(a, width) if isinstance(a, np.ndarray)
                         else jnp.pad(a, width))
@@ -939,6 +1119,8 @@ class RoundPipeline:
                        and len(sims) == 1 and not sel_spec.select_all
                        and cfg0.rounds >= 24)
         self._eval = _eval_program(self.spec, self._model_fns.evaluate)
+        # the device memory ``stream_cohort`` weighs the vmapped cohort by
+        self._bytes_limit = device_bytes_limit()
         self.done = [False] * s
         self._pending_free = []   # freed slots quarantined for one round
 
@@ -1184,10 +1366,20 @@ class RoundPipeline:
                 and all(len(w0.scheds[i].fresh_rows) == nf_b
                         and len(w0.scheds[i].landing) == ns_b
                         for i in groups0))
-        shapes = (r_b, tb, g_b, nf_b, ns_b, all_valid)
+        streamed = stream_cohort(self.d_pad, r_b, self._bytes_limit)
+        if streamed:
+            self._check_streamable(r_b)
+            # rows this wide barely fit: free each Simulator's own starting
+            # row, which the stacked parameters replaced and finalize
+            # rewrites (``flat_params`` is stale during a run anyway)
+            for sim in sims:
+                sim.flat_params = None
+        shapes = (r_b, tb, g_b, nf_b, ns_b, all_valid, streamed)
         trained = sum(len(w.rowq) for w in works)
         self.stats.trained_rows += trained
         self.stats.pad_rows += len(works) * nflat * r_b - trained
+        if streamed:
+            self.stats.streamed_rows += trained
 
         # a faulted batch appends the per-row corruption multipliers to the
         # floats buffer (static layout — pipeline_key keeps faulted and
@@ -1334,6 +1526,9 @@ class RoundPipeline:
                         # attacker flags ride the ints buffer, p-replicated
                         # like the rest of the group metadata
                         ints_parts.append(agg_att.ravel())
+                    if streamed:
+                        # the streamed loop's trip count: the real rows
+                        ints_parts.append(np.asarray([nloc_q[q]], np.int32))
                     per_shard.append(np.concatenate(ints_parts))
                     parts = [floats_j]
                     if self._faulty:
@@ -1346,6 +1541,22 @@ class RoundPipeline:
             chunks.append(np.stack(per_shard))
         ints_all = np.stack(chunks)        # already int32 throughout
         return ints_all, floats_all, shapes, offs, gmaps
+
+    def _check_streamable(self, r_b: int) -> None:
+        """A streamed round has no full aggregation operand, which these
+        programs need; refuse them rather than materialize it."""
+        bad = [name for name, on in (
+            ("guarded aggregation", self._guard is not None),
+            ("a robust aggregator", self._robust is not None),
+            ("a coordinated attack", self._attack is not None),
+            ("a device mesh (sweep or participant sharding)",
+             self.mesh is not None),
+            ("the telemetry lane (telemetry >= 2)", self._lane)) if on]
+        if bad:
+            raise NotImplementedError(
+                f"rows of D = {self.d} do not fit the device as a vmapped "
+                f"cohort of {r_b}, so the round streams its participants, "
+                f"which cannot be combined with {', '.join(bad)}")
 
     def _put(self, ints, floats):
         """Upload a chunk's packed buffers; returns them on the device with

@@ -89,6 +89,7 @@ PIPELINE_COUNTERS = (
     "pipeline_feedback_fetches",
     "pipeline_trained_rows",    # packed rows that train (survivors)
     "pipeline_pad_rows",        # padding rows of the chosen shape bucket
+    "pipeline_streamed_rows",   # rows trained one at a time (streamed round)
     "pipeline_checked_in",      # checked-in learners handed to selection
 )
 # Compile work seen while an enabled session is open
@@ -100,6 +101,18 @@ COMPILE_COUNTERS = (
     "compile_cache_misses",         # ... and writes after a miss
 )
 DISPATCH_KINDS = ("round", "eval", "cache_grow", "repack")
+
+# ---------------------------------------------------------------------------
+# ``jax.named_scope`` names of the device round program (``sim/pipeline.py``)
+# and the eval program; a profile's device ops carry them in their op path.
+DEVICE_SCOPES = (
+    "train",        # local training (gather of batches, SGD steps, delta)
+    "fresh_sum",    # streamed round: fresh rows summed, then their mean
+    "cache",        # straggler rows to their slots, operand gather
+    "aggregate",    # guard/robust screens, Eq. 2 weights and aggregate
+    "apply",        # server step
+    "eval",         # held-out accuracy and loss
+)
 
 # ---------------------------------------------------------------------------
 # Host-side tracer span names (Chrome trace-event JSON, Perfetto-loadable).

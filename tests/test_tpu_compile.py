@@ -13,7 +13,8 @@ described chip cannot be read back without one.
 
 Widths: the mlp learner's D=12,835 pads to 14,336 and the transformer
 learner's D=213,312 to 215,040 (the kernels' 2048-column block); n=16 is
-a bucket-padded cohort.
+a bucket-padded cohort.  MiniCPM-2B at 4 layers and 15,360 ids,
+D=279,597,312, pads to 279,599,104: the streamed round's operand.
 """
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from repro.kernels.trimmed_agg import ops as trimmed_ops
 N = 16
 D_MLP = 14_336
 D_LM = 215_040
+D_MINICPM = 279_599_104
 
 
 @pytest.fixture(scope="module")
@@ -95,6 +97,19 @@ def test_sweep_fused_staleness_aggregate_compiles_g4(tpu_hlo):
             u, f, t, b, v, interpret=False),
         ((g, N, D_MLP), F32), ((g, N), BOOL), ((g, N), I32), ((g,), F32),
         ((g, N), BOOL))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("ns", [0, 2])
+def test_streamed_staleness_apply_compiles(tpu_hlo, ns):
+    """The streamed round's call: the fresh mean, carrying its fresh count,
+    and ``ns`` landing stale rows, at MiniCPM-2B's padded width."""
+    hlo = tpu_hlo(
+        lambda p, u, f, t, v, s: sa.sweep_fused_staleness_apply(
+            p, u, f, t, v, s, interpret=False),
+        ((1, D_MINICPM), F32), ((1, 1 + ns, D_MINICPM), F32),
+        ((1, 1 + ns), F32), ((1, 1 + ns), I32), ((1, 1 + ns), BOOL),
+        ((1, 2), F32))
     assert "tpu_custom_call" in hlo
 
 
