@@ -178,7 +178,7 @@ def phase_sweep(n_learners=1000, rounds=20, eval_every=10) -> str:
 
 
 def phase_lm(n_learners=100, rounds=4, eval_every=2, model_params=()) -> str:
-    from repro.kernels.staleness_agg.staleness_agg import D_BLK
+    from repro.kernels.staleness_agg.staleness_agg import col_quantum
     from repro.sim import SimConfig
     from repro.sim.engine import Substrate
     base = SimConfig(model="transformer", benchmark="tokens",
@@ -189,7 +189,7 @@ def phase_lm(n_learners=100, rounds=4, eval_every=2, model_params=()) -> str:
     fused = _run_sim(base, sub)
     flat = _run_sim(dataclasses.replace(base, fused_rounds=False), sub)
     d = fused.params.size
-    return (f"D={d} d_pad={d + (-d) % D_BLK} "
+    return (f"D={d} d_pad={d + (-d) % col_quantum(d)} "
             f"loss={fused.loss!r} " + compare("flat", flat, fused))
 
 

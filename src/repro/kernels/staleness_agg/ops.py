@@ -5,8 +5,9 @@ in-kernel Eq. 2 weights, weighted aggregate in one grid traversal);
 ``fused=False`` keeps the original two-launch pipeline (partials kernel ->
 host O(n) weights -> aggregate kernel) for A/B comparison.
 
-These wrappers are deliberately *not* jitted: D is padded to the 2048-lane
-block and (by default) the participant axis is padded to a power-of-two
+These wrappers are deliberately *not* jitted: D is padded to the fused
+kernels' column quantum (``col_quantum``; the two-launch pair's ``D_BLK``)
+and (by default) the participant axis is padded to a power-of-two
 bucket on the host, so repeated calls with varying fresh+stale counts reuse
 one compiled kernel per bucket instead of recompiling per exact shape.
 ``interpret=None`` auto-detects the backend (compiled on TPU, interpreter
@@ -20,7 +21,7 @@ import numpy as np
 from repro.core.aggregation import bucket_pad
 from repro.core.staleness import EPS, SCALING_RULES
 from repro.kernels.staleness_agg.staleness_agg import (
-    D_BLK, deviation_partials, fused_staleness_aggregate,
+    D_BLK, col_quantum, deviation_partials, fused_staleness_aggregate,
     fused_staleness_apply, sweep_fused_staleness_aggregate,
     sweep_fused_staleness_apply, weighted_aggregate)
 
@@ -35,7 +36,7 @@ def staleness_aggregate(updates, fresh, tau, *, rule: str = "relay",
     n, D = np.shape(updates)
     if fused:
         u, fr, ta, valid = bucket_pad(updates, fresh, tau, bucketed=bucketed,
-                                      lane_block=D_BLK)
+                                      lane_block=col_quantum(D))
         agg, w = fused_staleness_aggregate(u, fr, ta, np.float32(beta),
                                            rule=rule, interpret=interpret,
                                            valid=valid)
@@ -65,7 +66,7 @@ def sweep_staleness_aggregate(updates, fresh, tau, *, valid=None,
     s, n, d = np.shape(updates)
     if valid is None:
         valid = np.ones((s, n), bool)
-    u = np.zeros((s, n, d + ((-d) % D_BLK)), np.float32)
+    u = np.zeros((s, n, d + ((-d) % col_quantum(d))), np.float32)
     u[:, :, :d] = np.asarray(updates)
     beta_vec = np.broadcast_to(np.asarray(beta, np.float32), (s,))
     agg, w = sweep_fused_staleness_aggregate(
@@ -87,7 +88,7 @@ def sweep_staleness_apply(params, updates, fresh, tau, *, valid=None,
     s, n, d = np.shape(updates)
     if valid is None:
         valid = np.ones((s, n), bool)
-    dp = d + ((-d) % D_BLK)
+    dp = d + ((-d) % col_quantum(d))
     u = np.zeros((s, n, dp), np.float32)
     u[:, :, :d] = np.asarray(updates)
     p = np.zeros((s, dp), np.float32)
@@ -112,7 +113,7 @@ def staleness_apply(params, updates, fresh, tau, *, rule: str = "relay",
     """
     n, D = np.shape(updates)
     u, fr, ta, valid = bucket_pad(updates, fresh, tau, bucketed=bucketed,
-                                  lane_block=D_BLK)
+                                  lane_block=col_quantum(D))
     p = np.zeros(u.shape[1], np.float32)
     p[:D] = np.asarray(params)
     new_p, w = fused_staleness_apply(p, u, fr, ta, np.float32(beta),

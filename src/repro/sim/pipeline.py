@@ -237,6 +237,10 @@ class PipelineStats:
     checked_in = property(
         lambda s: s._counter("checked_in").value,
         lambda s, v: setattr(s._counter("checked_in"), "value", v))
+    # grid steps of the SAA kernel's launches: 2 x groups x column blocks
+    saa_kernel_steps = property(
+        lambda s: s._counter("saa_kernel_steps").value,
+        lambda s, v: setattr(s._counter("saa_kernel_steps"), "value", v))
 
     def as_dict(self) -> dict:
         per_round = max(self.rounds, 1)
@@ -259,6 +263,7 @@ class PipelineStats:
             "pad_rows": self.pad_rows,
             "streamed_rows": self.streamed_rows,
             "checked_in": self.checked_in,
+            "saa_kernel_steps": self.saa_kernel_steps,
             "guard": dict(self.guard),
         }
 
@@ -504,23 +509,18 @@ def _round_body(params, cache, opt_state, x_tr, y_tr, ints, floats, shapes,
         # through the trimmed_agg kernel (one cross-substrate story)
         saa_kernel = use_kernel and attack is None and robust is None
         if saa_kernel:
+            # rows are already at the kernel's padded width (``d_pad``)
             from repro.kernels.staleness_agg.staleness_agg import (
-                D_BLK, sweep_fused_staleness_apply,
-                sweep_fused_staleness_aggregate)
-            d = u.shape[-1]
-            pad = (-d) % D_BLK
-            up = jnp.pad(u, ((0, 0), (0, 0), (0, pad)))
+                sweep_fused_staleness_apply, sweep_fused_staleness_aggregate)
             if yogi:
                 agg_out, _ = sweep_fused_staleness_aggregate(
-                    up, agg_fresh, agg_tau, beta_g, agg_valid,
+                    u, agg_fresh, agg_tau, beta_g, agg_valid,
                     rule=kernel_rule)
-                agg_out = agg_out[:, :d]
             else:
                 scal = jnp.stack([beta_g, lr_g], axis=1)
                 new_rows, _ = sweep_fused_staleness_apply(
-                    jnp.pad(rows_old, ((0, 0), (0, pad))), up, agg_fresh,
-                    agg_tau, agg_valid, scal, rule=kernel_rule)
-                new_rows = new_rows[:, :d]
+                    rows_old, u, agg_fresh, agg_tau, agg_valid, scal,
+                    rule=kernel_rule)
         elif robust_coord:
             # robust-of-weighted: per-coordinate trimmed mean of the SAA-
             # weighted rows (trimmed_weighted_aggregate's formula, vmapped)
@@ -942,19 +942,20 @@ class RoundPipeline:
         self._model_fns = sims[0]._model_fns
         # persistent D-blocked layout: when every round runs the staleness-
         # agg Pallas kernel (no attack/robust rewrite bypassing it), the
-        # params/cache/opt buffers are allocated ONCE at the kernel's
-        # D_BLK-padded width instead of jnp.pad-ing the operand each round.
+        # params/cache/opt buffers are allocated ONCE at the width the
+        # kernel's column blocks divide (``col_quantum``: under 0.1% more
+        # columns) instead of jnp.pad-ing the operand each round.
         # For paper-scale D the per-round pad was cheap; for model-zoo D
         # (1e5+) it is an O(G·N·D) copy in the hot loop.  Pad columns hold
         # exact zeros for the life of the run (train deltas are zero-padded
         # at the source, every server op is columnwise), and every true-D
         # reduction (lane norms, guard screen) slices before reducing, so
         # results are bit-identical to the per-round-pad layout.
-        saa = (cfg0.use_agg_kernel and attack_key(cfg0) is None
-               and robust_key(cfg0) is None)
-        if saa:
-            from repro.kernels.staleness_agg.staleness_agg import D_BLK
-            self.d_pad = self.d + ((-self.d) % D_BLK)
+        self._saa_kernel = (cfg0.use_agg_kernel and attack_key(cfg0) is None
+                            and robust_key(cfg0) is None)
+        if self._saa_kernel:
+            from repro.kernels.staleness_agg.staleness_agg import col_quantum
+            self.d_pad = self.d + ((-self.d) % col_quantum(self.d))
         else:
             self.d_pad = self.d
         d, pad_w = self.d, self.d_pad - self.d
@@ -1380,6 +1381,14 @@ class RoundPipeline:
         self.stats.pad_rows += len(works) * nflat * r_b - trained
         if streamed:
             self.stats.streamed_rows += trained
+        if self._saa_kernel:
+            # the operand a kernel launch sees: the streamed round's fresh
+            # mean and landing rows, else the vmapped round's n_b rows
+            from repro.kernels.staleness_agg.staleness_agg import col_block
+            n_op = 1 + ns_b if streamed else n_b
+            self.stats.saa_kernel_steps += (
+                len(works) * nflat * 2 * g_b * self.d_pad
+                // col_block(n_op, self.d_pad))
 
         # a faulted batch appends the per-row corruption multipliers to the
         # floats buffer (static layout — pipeline_key keeps faulted and

@@ -91,6 +91,7 @@ PIPELINE_COUNTERS = (
     "pipeline_pad_rows",        # padding rows of the chosen shape bucket
     "pipeline_streamed_rows",   # rows trained one at a time (streamed round)
     "pipeline_checked_in",      # checked-in learners handed to selection
+    "pipeline_saa_kernel_steps",  # SAA kernel grid steps launched
 )
 # Compile work seen while an enabled session is open
 # (``repro.telemetry.compile``).

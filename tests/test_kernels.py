@@ -6,6 +6,8 @@ import pytest
 
 from repro.kernels.staleness_agg import ops as agg_ops
 from repro.kernels.staleness_agg import ref as agg_ref
+from repro.kernels.staleness_agg.staleness_agg import (
+    BLOCK_VMEM, D_BLK, col_block, col_quantum, sweep_fused_staleness_apply)
 from repro.kernels.swa_attention import ops as swa_ops
 from repro.kernels.swa_attention import ref as swa_ref
 from repro.kernels.wkv6 import ops as wkv_ops
@@ -73,13 +75,26 @@ def test_fused_staleness_apply_in_place_step():
                                rtol=1e-4, atol=1e-5)
 
 
+# (n, D): the first a bucket-padded cohort at the narrowest block; the rest
+# at widths the wrappers pad to where every n takes blocks wider than
+# D_BLK, two or more of them: 131,072 columns for one or two rows, 65,536
+# for three, 32,768 for eight, 4,096 for 64
+SWEEP_SHAPES = [(6, 4096 + 33), (1, 2**18 - 100), (2, 2**18 - 100),
+                (3, 2**18 - 100), (8, 2**16 - 50), (64, 2**13 - 5)]
+
+
+@pytest.mark.parametrize("n,D", SWEEP_SHAPES)
 @pytest.mark.parametrize("rule", ["equal", "relay"])
-def test_sweep_staleness_apply_matches_aggregate_kernel(rule):
+def test_sweep_staleness_apply_matches_aggregate_kernel(rule, n, D):
     """Sweep-axis fused server step == the sweep aggregate kernel's result
     applied with per-cell lr (same blockwise partials math, params buffer
-    aliased input->output), and an all-invalid cell keeps its bits."""
+    aliased input->output), both match the jnp oracle per cell, and an
+    all-invalid cell keeps its bits."""
+    d_pad = D + (-D) % col_quantum(D)
+    blk = col_block(n, d_pad)
+    assert (blk > D_BLK and d_pad // blk >= 2) or D == 4096 + 33
     rng = np.random.default_rng(7)
-    S, n, D = 3, 6, 4096 + 33
+    S = 3
     U = rng.standard_normal((S, n, D)).astype(np.float32)
     params = rng.standard_normal((S, D)).astype(np.float32)
     fresh = rng.random((S, n)) < 0.5
@@ -98,6 +113,60 @@ def test_sweep_staleness_apply_matches_aggregate_kernel(rule):
     np.testing.assert_array_equal(
         np.asarray(new_p), params + lr[:, None] * np.asarray(agg_k))
     np.testing.assert_array_equal(np.asarray(new_p)[2], params[2])
+    for s in (0, 1):
+        agg_r, w_r = agg_ref.staleness_aggregate_ref(
+            jnp.asarray(U[s]), jnp.asarray(fresh[s]), jnp.asarray(tau[s]),
+            rule=rule, beta=float(beta[s]))
+        np.testing.assert_allclose(np.asarray(w_k)[s], np.asarray(w_r),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(agg_k)[s], np.asarray(agg_r),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_one_fresh_row_applies_exactly():
+    """A one-row operand that is the mean of n_F fresh updates weighs
+    n_F / n_F = 1 exactly, so the server step is params + lr * u bit for
+    bit at the widest block (the streamed round when no stale row lands)."""
+    rng = np.random.default_rng(3)
+    d = 2**18
+    assert col_block(1, d) == 2**17
+    u = rng.standard_normal((1, 1, d)).astype(np.float32)
+    params = rng.standard_normal((1, d)).astype(np.float32)
+    lr = np.float32(0.5)   # a power of two: no multiply-add contraction moves a bit
+    new_p, w = sweep_fused_staleness_apply(
+        params, u, np.full((1, 1), 8.0, np.float32), np.zeros((1, 1), np.int32),
+        np.ones((1, 1), bool), np.array([[0.35, lr]], np.float32))
+    np.testing.assert_array_equal(np.asarray(w), [[1.0]])
+    np.testing.assert_array_equal(np.asarray(new_p), params + lr * u[:, 0])
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 8, 16, 64, 127, 512])
+@pytest.mark.parametrize("d", [14_336, 215_040, 2**18, 279_707_648])
+def test_col_block_divides_and_fits(n, d):
+    """The block divides the padded width, is a multiple of D_BLK, its
+    double-buffered blocks (n operand rows, params, out) fit BLOCK_VMEM,
+    and a cohort of more than about 120 rows keeps D_BLK."""
+    blk = col_block(n, d)
+    assert d % blk == 0 and blk % D_BLK == 0
+    assert blk == D_BLK or 2 * 4 * (n + 2) * blk <= BLOCK_VMEM
+    if n >= 64:
+        assert blk <= 2 * D_BLK
+    if n >= 127:
+        assert blk == D_BLK
+
+
+@pytest.mark.parametrize("d,quantum", [
+    (12_835, 2048), (213_312, 2048), (2**18 - 100, 2**17),
+    (279_597_312, 2**17)])
+def test_col_quantum_keeps_the_padding_small(d, quantum):
+    """Rows pad to the widest one-row block that adds at most d / 1024
+    columns, else to D_BLK: MiniCPM-2B's 279,597,312 columns gain 110,336
+    (0.04%), the small learners keep their 2048-multiple widths."""
+    q = col_quantum(d)
+    assert q == quantum
+    d_pad = d + (-d) % q
+    assert d_pad - d <= max(2047, d // 1024)
+    assert col_block(1, d_pad) == q
 
 
 def test_staleness_agg_deviation_partials():

@@ -9,7 +9,7 @@
     the same substrates with full bit-parity, fused vs flat vs chunked
     (vs sharded on multi-device legs);
   * the D-blocked kernel layout — ``use_agg_kernel=True`` keeps all round
-    buffers at D rounded up to the kernel's 2048-column block — matches
+    buffers at D rounded up to the kernel's column quantum — matches
     the unblocked per-stage reference bitwise, and the pad columns stay
     exactly zero for the life of the run;
   * the LM round program keeps the hot-path hygiene invariants: clean
@@ -129,9 +129,10 @@ def test_legacy_engine_rejects_non_mlp_models():
 
 
 def test_dblocked_kernel_matches_unblocked_reference():
-    """use_agg_kernel keeps the fused pipeline's buffers at d_pad (a 2048
-    multiple > D for the LM); the per-stage flat path pads transiently per
-    kernel call.  Same math, same bits."""
+    """use_agg_kernel keeps the fused pipeline's buffers at d_pad (a
+    ``col_quantum`` multiple > D for the LM, 2048 at its width); the
+    per-stage flat path pads transiently per kernel call.  Same math, same
+    bits."""
     cfg = SimConfig(use_agg_kernel=True, **LM_BASE)
     blocked = Simulator(cfg).run()
     unblocked = Simulator(
@@ -153,10 +154,10 @@ def test_dblocked_kernel_sharded_matches_unblocked_reference():
 
 
 def test_padded_layout_pad_columns_stay_zero():
-    from repro.kernels.staleness_agg.staleness_agg import D_BLK
+    from repro.kernels.staleness_agg.staleness_agg import col_quantum
     cfg = SimConfig(use_agg_kernel=True, **LM_BASE)
     pipe = RoundPipeline([Simulator(cfg)])
-    assert pipe.d_pad > pipe.d and pipe.d_pad % D_BLK == 0
+    assert pipe.d_pad > pipe.d and pipe.d_pad % col_quantum(pipe.d) == 0
     pipe.run()
     rows = np.asarray(jax.device_get(pipe.params)).reshape(-1, pipe.d_pad)
     assert (rows[:, pipe.d:] == 0).all(), \

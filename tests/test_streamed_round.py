@@ -147,6 +147,67 @@ def test_streamed_simulation_matches_vmapped(case, monkeypatch):
     assert ss["streamed_rows"] == ss["trained_rows"] > 0
 
 
+@pytest.mark.parametrize("ns", [1, 2])
+@pytest.mark.parametrize("rule", ["equal", "relay"])
+def test_streamed_stale_rows_kernel_matches_jnp(rule, ns):
+    """Stale rows landing in a streamed round, at a width whose kernel
+    block is wide (131,072 columns for the fresh mean alone, 65,536 with
+    two stale rows): the SAA kernel's server step gives the jnp path's
+    (``counted_weights_and_aggregate_by_id``) within 1e-6 relative, and
+    leaves the pad columns zero."""
+    import jax.numpy as jnp
+    from repro.core.staleness import RULE_ID
+    from repro.kernels.staleness_agg.staleness_agg import (D_BLK, col_block,
+                                                           col_quantum)
+    d = 2**18 - 100
+    d_pad = d + (-d) % col_quantum(d)
+    assert col_block(1 + ns, d_pad) > D_BLK
+    rng = np.random.default_rng(11 + ns)
+    nf_b, n_f, cap = 4, 3, 3
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    pad = lambda a: np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, d_pad - d)])
+    params = f32(pad(rng.standard_normal((2, d)).astype(np.float32)))
+    cache = f32(pad(0.1 * rng.standard_normal((cap + 1, d))
+                    .astype(np.float32)))
+    fsum = [f32(0.1 * n_f * rng.standard_normal((1, 1000))),
+            f32(0.1 * n_f * rng.standard_normal((1, d - 1000)))]
+    fresh = np.zeros((1, nf_b + ns), bool)
+    fresh[0, :n_f] = True
+    valid = fresh.copy()
+    valid[0, nf_b:] = True
+    tau = np.zeros((1, nf_b + ns), np.int32)
+    tau[0, nf_b:] = np.arange(2, 2 + ns)
+    out = {}
+    for kernel in (False, True):
+        out[kernel] = pipeline._streamed_aggregate(
+            params, cache, None, fsum, jnp.zeros(8), jnp.zeros(8),
+            jnp.arange(ns, dtype=jnp.int32)[None], jnp.zeros(1, jnp.int32),
+            jnp.asarray(tau), jnp.asarray(fresh), jnp.asarray(valid),
+            jnp.ones(1, bool), f32([0.35]), f32([1.0]),
+            jnp.full(1, RULE_ID[rule], jnp.int32), nf_b=nf_b, yogi=False,
+            use_kernel=kernel, kernel_rule=rule)[0]
+    assert _rel(out[True], out[False]) <= 1e-6
+    assert _rel(out[True], params) > 1e-3
+    assert not np.any(np.asarray(out[True])[:, d:])
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "kernel"])
+def test_saa_kernel_steps_count_the_grid(kernel, monkeypatch):
+    """``pipeline_saa_kernel_steps`` sums the SAA kernel's grid steps over
+    the rounds: two phases over the column blocks of the streamed round's
+    one-row operand (here 7 blocks of 2,048 at D = 12,835); none without
+    the kernel."""
+    from repro.kernels.staleness_agg.staleness_agg import col_block
+    _force(monkeypatch, True)
+    pipe = RoundPipeline([Simulator(_cfg("fresh_only", kernel))])
+    pipe.run()
+    rounds = pipe.stats.rounds
+    want = 2 * pipe.d_pad // col_block(1, pipe.d_pad) * rounds if kernel else 0
+    assert rounds > 0 and pipe.stats.as_dict()["saa_kernel_steps"] == want
+    assert pipe.telemetry.registry.value("pipeline_saa_kernel_steps") == want
+    assert (pipe.d_pad != pipe.d) == kernel
+
+
 def test_choice_keeps_vmap_for_the_mlp_cells():
     """The mlp cells' rows (D = 12,835) stay vmapped on a 16 GB chip and on
     a backend that reports no memory; MiniCPM-2B's 4-layer rows stream."""

@@ -12,9 +12,12 @@ compilation cache is off around these compiles: an entry written for a
 described chip cannot be read back without one.
 
 Widths: the mlp learner's D=12,835 pads to 14,336 and the transformer
-learner's D=213,312 to 215,040 (the kernels' 2048-column block); n=16 is
-a bucket-padded cohort.  MiniCPM-2B at 4 layers and 15,360 ids,
-D=279,597,312, pads to 279,599,104: the streamed round's operand.
+learner's D=213,312 to 215,040 (``col_quantum``: 2048 columns at these
+widths); n=16 is a bucket-padded cohort.  MiniCPM-2B at 4 layers and
+15,360 ids, D=279,597,312, pads to 279,707,648 (131,072 columns): the
+streamed round's operand, whose one to three rows take 64K-128K-column
+blocks.  At 2**18 columns a 64-row cohort takes 4,096-column blocks and
+three rows take 65,536.
 """
 import jax
 import jax.numpy as jnp
@@ -28,7 +31,7 @@ from repro.kernels.trimmed_agg import ops as trimmed_ops
 N = 16
 D_MLP = 14_336
 D_LM = 215_040
-D_MINICPM = 279_599_104
+D_MINICPM = 279_707_648
 
 
 @pytest.fixture(scope="module")
@@ -80,23 +83,25 @@ def test_fused_staleness_apply_compiles(tpu_hlo, d):
     assert "tpu_custom_call" in hlo
 
 
-def test_sweep_fused_staleness_apply_compiles_g4(tpu_hlo):
+@pytest.mark.parametrize("n,d", [(N, D_MLP), (64, 2**18)])
+def test_sweep_fused_staleness_apply_compiles_g4(tpu_hlo, n, d):
     g = 4
     hlo = tpu_hlo(
         lambda p, u, f, t, v, s: sa.sweep_fused_staleness_apply(
             p, u, f, t, v, s, interpret=False),
-        ((g, D_MLP), F32), ((g, N, D_MLP), F32), ((g, N), BOOL),
-        ((g, N), I32), ((g, N), BOOL), ((g, 2), F32))
+        ((g, d), F32), ((g, n, d), F32), ((g, n), BOOL),
+        ((g, n), I32), ((g, n), BOOL), ((g, 2), F32))
     assert "tpu_custom_call" in hlo
 
 
-def test_sweep_fused_staleness_aggregate_compiles_g4(tpu_hlo):
+@pytest.mark.parametrize("n,d", [(N, D_MLP), (3, 2**18)])
+def test_sweep_fused_staleness_aggregate_compiles_g4(tpu_hlo, n, d):
     g = 4
     hlo = tpu_hlo(
         lambda u, f, t, b, v: sa.sweep_fused_staleness_aggregate(
             u, f, t, b, v, interpret=False),
-        ((g, N, D_MLP), F32), ((g, N), BOOL), ((g, N), I32), ((g,), F32),
-        ((g, N), BOOL))
+        ((g, n, d), F32), ((g, n), BOOL), ((g, n), I32), ((g,), F32),
+        ((g, n), BOOL))
     assert "tpu_custom_call" in hlo
 
 
